@@ -19,11 +19,11 @@ Then two PR 7 hot-path artifacts:
   HOT-PATH GATE — warm `engine_run` with the vectorized fast path
       (EngineSpec.fastpath=True, the default) vs the pre-overhaul reference
       ops (fastpath=False: per-access serial lookups, full argsort selection,
-      per-vpn shootdown scan, f32 histogram adds).  Each leg runs in its own
-      subprocess (same isolation discipline as the fleet throughput gate) and
-      dumps its per-interval stats + final counters; the parent ASSERTS the
-      legs are bit-identical and that the rainbow fast path clears
-      GATE_FLOOR x the reference.
+      per-vpn shootdown scan, f32 histogram adds).  Both legs run in this
+      process, one after the other (a chip belongs to one process at a
+      time); each keeps its per-interval stats + final counters, and the
+      gate ASSERTS the legs are bit-identical and that the rainbow fast path
+      clears GATE_FLOOR x the reference.
 
 Results land in BENCH_engine.json at the repo root (aggregated by
 benchmarks.run, schema-checked by scripts/ci.sh).
@@ -32,18 +32,12 @@ Run: PYTHONPATH=src python -m benchmarks.engine_throughput
 """
 from __future__ import annotations
 
-import json
-import os
-import shutil
-import subprocess
-import sys
-import tempfile
 import time
 
 import jax
 import numpy as np
 
-from benchmarks.common import QUICK, ROOT, emit, write_bench_json
+from benchmarks.common import QUICK, emit, write_bench_json
 from repro.sim.config import MachineConfig
 from repro.sim.runner import simulate_eager
 
@@ -163,12 +157,12 @@ def _profile() -> dict:
 
 
 # ---------------------------------------------------------------------------
-# Hot-path gate (fastpath=True vs fastpath=False, subprocess-isolated)
+# Hot-path gate (fastpath=True vs fastpath=False, in this process)
 # ---------------------------------------------------------------------------
 
 
-def _gate_child(mode: str, out_path: str) -> None:
-    """One gate leg in a fresh process: warm engine_run per policy + digest.
+def _gate_leg(mode: str) -> dict:
+    """One gate leg: warm engine_run per policy + digest.
 
     `mode` selects the compiled program: "fast" = the PR 7 vectorized hot
     path (EngineSpec default), "reference" = the pre-overhaul ops kept under
@@ -203,79 +197,50 @@ def _gate_child(mode: str, out_path: str) -> None:
             np.asarray(x, np.float64).reshape(-1).tolist() for x in stats
         ] + [float(np.asarray(c)) for c in state.sim.counters]
         legs[policy] = {"seconds": t, "digest": digest}
-    with open(out_path, "w") as f:
-        json.dump({
-            "mode": mode,
-            "intervals": INTERVALS,
-            "accesses_per_interval": ACCESSES,
-            "legs": legs,
-        }, f)
+    return legs
 
 
 def _gate() -> dict:
-    """Run both legs in subprocesses; assert bit-identity + the rainbow floor."""
-    tmp = tempfile.mkdtemp(prefix="engine_gate_")
-
-    def child(mode: str) -> dict:
-        out = os.path.join(tmp, f"{mode}.json")
-        env = dict(
-            os.environ,
-            PYTHONPATH=os.pathsep.join(
-                [os.path.join(ROOT, "src"), ROOT,
-                 os.environ.get("PYTHONPATH", "")]
-            ),
+    """Run both legs; assert bit-identity + the rainbow floor."""
+    ref = _gate_leg("reference")
+    fast = _gate_leg("fast")
+    total_accesses = INTERVALS * ACCESSES
+    rows, per_policy = [], {}
+    for policy in GATE_POLICIES:
+        a, b = ref[policy], fast[policy]
+        assert a["digest"] == b["digest"], (
+            f"hot-path gate FAILED: fastpath SimMetrics inputs diverge "
+            f"from the reference ops on {policy}"
         )
-        args = [sys.executable, "-m", "benchmarks.engine_throughput",
-                "--gate-child", mode, out]
-        r = subprocess.run(args, env=env, cwd=ROOT, capture_output=True,
-                           text=True, timeout=3600)
-        if r.returncode != 0:
-            raise RuntimeError(f"gate child {mode} failed:\n{r.stderr[-3000:]}")
-        with open(out) as f:
-            return json.load(f)
-
-    try:
-        ref = child("reference")
-        fast = child("fast")
-        total_accesses = INTERVALS * ACCESSES
-        rows, per_policy = [], {}
-        for policy in GATE_POLICIES:
-            a, b = ref["legs"][policy], fast["legs"][policy]
-            assert a["digest"] == b["digest"], (
-                f"hot-path gate FAILED: fastpath SimMetrics inputs diverge "
-                f"from the reference ops on {policy}"
-            )
-            sp = a["seconds"] / b["seconds"]
-            per_policy[policy] = {
-                "reference_s": round(a["seconds"], 4),
-                "fast_s": round(b["seconds"], 4),
-                "speedup": round(sp, 3),
-                "accesses_per_sec": round(total_accesses / b["seconds"], 1),
-            }
-            rows.append({
-                "policy": policy,
-                "intervals": INTERVALS,
-                "accesses_per_interval": ACCESSES,
-                "reference_s": round(a["seconds"], 4),
-                "fast_s": round(b["seconds"], 4),
-                "speedup": round(sp, 3),
-                "bit_identical": True,
-            })
-        speedup = per_policy[POLICY]["speedup"]
-        if speedup < GATE_FLOOR:
-            raise RuntimeError(
-                f"engine hot-path gate FAILED: fastpath warm engine_run is "
-                f"only {speedup:.2f}x the pre-overhaul reference on {POLICY} "
-                f"(floor: {GATE_FLOOR}x)"
-            )
-        return {
-            "rows": rows,
-            "speedup": speedup,
-            "per_policy": per_policy,
-            "floor": GATE_FLOOR,
+        sp = a["seconds"] / b["seconds"]
+        per_policy[policy] = {
+            "reference_s": round(a["seconds"], 4),
+            "fast_s": round(b["seconds"], 4),
+            "speedup": round(sp, 3),
+            "accesses_per_sec": round(total_accesses / b["seconds"], 1),
         }
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
+        rows.append({
+            "policy": policy,
+            "intervals": INTERVALS,
+            "accesses_per_interval": ACCESSES,
+            "reference_s": round(a["seconds"], 4),
+            "fast_s": round(b["seconds"], 4),
+            "speedup": round(sp, 3),
+            "bit_identical": True,
+        })
+    speedup = per_policy[POLICY]["speedup"]
+    if speedup < GATE_FLOOR:
+        raise RuntimeError(
+            f"engine hot-path gate FAILED: fastpath warm engine_run is "
+            f"only {speedup:.2f}x the pre-overhaul reference on {POLICY} "
+            f"(floor: {GATE_FLOOR}x)"
+        )
+    return {
+        "rows": rows,
+        "speedup": speedup,
+        "per_policy": per_policy,
+        "floor": GATE_FLOOR,
+    }
 
 
 def run() -> None:
@@ -295,8 +260,7 @@ def run() -> None:
         "engine_hotpath_gate", gate["rows"], t2,
         derived=(
             f"fastpath_vs_reference={gate['speedup']:.2f}x"
-            f"(floor {GATE_FLOOR}x);policies={len(GATE_POLICIES)};"
-            "subprocess-isolated"
+            f"(floor {GATE_FLOOR}x);policies={len(GATE_POLICIES)}"
         ),
     )
     write_bench_json("engine", {
@@ -323,7 +287,4 @@ def run() -> None:
 
 
 if __name__ == "__main__":
-    if len(sys.argv) >= 4 and sys.argv[1] == "--gate-child":
-        _gate_child(sys.argv[2], sys.argv[3])
-    else:
-        run()
+    run()

@@ -11,7 +11,7 @@ columns x 3 seeds.
 
 The run leans on the whole atlas-scale fast path: every (scenario, preset)
 pair is its own compile signature, so the CompileCache + persistent
-compilation cache (REPRO_FLEET_CACHE_DIR) decide whether a repeat/resumed
+compilation cache (repro.utils.compile_cache) decide whether a repeat/resumed
 atlas recompiles anything; the prefetch pipeline stages ahead; the journal
 batches retirement I/O.
 

@@ -38,7 +38,7 @@ def load_cells(out_dir: str = "experiments/dryrun", tag: str = ""):
 
 def rows_from_cells(cells):
     from repro.configs import get_config, get_shape
-    from repro.launch.hlo_analysis import PEAK_FLOPS, HBM_BW, decode_bytes_global
+    from repro.launch.hlo_analysis import decode_bytes_global, peaks
 
     rows = []
     for c in cells:
@@ -47,7 +47,8 @@ def rows_from_cells(cells):
             # correct the HloCostAnalysis DUS full-buffer artifact (§Roofline)
             cfg = get_config(c["arch"])
             shape = get_shape(c["shape"])
-            mem_corr = decode_bytes_global(cfg, shape) / c["chips"] / HBM_BW
+            hbm_bw = peaks(c["device_kind"])["hbm_bw"]
+            mem_corr = decode_bytes_global(cfg, shape) / c["chips"] / hbm_bw
             r["memory_s"] = mem_corr
             bound = max(r["compute_s"], mem_corr, r["collective_s"])
             r["dominant"] = max(

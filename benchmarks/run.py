@@ -11,8 +11,30 @@ from __future__ import annotations
 import glob
 import json
 import os
+import subprocess
 import sys
-import traceback
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+MODULES = (
+    "paper_table6_storage",  # cheap first
+    "paper_fig1_table12",
+    "paper_fig7_mpki",
+    "paper_fig8_tlb_cycles",
+    "paper_fig9_breakdown",
+    "paper_fig10_ipc",
+    "paper_fig11_traffic",
+    "paper_fig12_energy",
+    "paper_fig15_runtime",
+    "paper_fig13_14_sensitivity",
+    "engine_throughput",
+    "fleet_throughput",
+    "timing_contention",
+    "nomad_async",
+    "policy_atlas",
+    "serving_rainbow",
+    "autotune_serving",
+    "roofline",
+)
 
 
 def aggregate() -> list[str]:
@@ -28,8 +50,6 @@ def aggregate() -> list[str]:
     printed "[gate FAIL]" into a green CI log and nobody looked; now
     `main()` and `--aggregate-only` both exit non-zero on it.
     """
-    from benchmarks.common import ROOT
-
     failures: list[str] = []
     paths = sorted(glob.glob(os.path.join(ROOT, "BENCH_*.json")))
     if not paths:
@@ -68,55 +88,15 @@ def main() -> None:
             sys.exit(1)
         print("\nall BENCH gates pass")
         return
-    from benchmarks import (
-        autotune_serving,
-        engine_throughput,
-        fleet_throughput,
-        nomad_async,
-        paper_fig1_table12,
-        paper_fig7_mpki,
-        paper_fig8_tlb_cycles,
-        paper_fig9_breakdown,
-        paper_fig10_ipc,
-        paper_fig11_traffic,
-        paper_fig12_energy,
-        paper_fig13_14_sensitivity,
-        paper_fig15_runtime,
-        paper_table6_storage,
-        policy_atlas,
-        roofline,
-        serving_rainbow,
-        timing_contention,
-    )
-
-    modules = [
-        paper_table6_storage,  # cheap first
-        paper_fig1_table12,
-        paper_fig7_mpki,
-        paper_fig8_tlb_cycles,
-        paper_fig9_breakdown,
-        paper_fig10_ipc,
-        paper_fig11_traffic,
-        paper_fig12_energy,
-        paper_fig15_runtime,
-        paper_fig13_14_sensitivity,
-        engine_throughput,
-        fleet_throughput,
-        timing_contention,
-        nomad_async,
-        policy_atlas,
-        serving_rainbow,
-        autotune_serving,
-        roofline,
-    ]
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [os.path.join(ROOT, "src"), ROOT, os.environ.get("PYTHONPATH", "")]))
     failed = []
-    for mod in modules:
-        name = mod.__name__.split(".")[-1]
-        print(f"\n===== {name} =====")
-        try:
-            mod.run()
-        except Exception:
-            traceback.print_exc()
+    for name in MODULES:
+        print(f"\n===== {name} =====", flush=True)
+        # one process per module, and none of JAX here: a chip belongs to
+        # one process at a time, and fleet_throughput starts its own
+        if subprocess.run([sys.executable, "-m", f"benchmarks.{name}"],
+                          cwd=ROOT, env=env).returncode:
             failed.append(name)
     failed += aggregate()
     if failed:

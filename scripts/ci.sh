@@ -100,7 +100,7 @@ EOF
 echo "== atlas smoke: policy atlas 2x2x2, streamed + journaled + resume-checked =="
 ATLAS_TMP="$(mktemp -d)"
 trap 'rm -rf "$ATLAS_TMP"' EXIT
-REPRO_FLEET_CACHE_DIR="$ATLAS_TMP/xla-cache" python -m benchmarks.policy_atlas \
+python -m benchmarks.policy_atlas \
     --scenarios 2 --policies 2 --seeds 2 \
     --journal "$ATLAS_TMP/atlas.jsonl" --out "$ATLAS_TMP/BENCH_atlas.json" \
     --resume-check

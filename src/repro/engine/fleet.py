@@ -15,8 +15,8 @@ and vmapped the seed fleet; this module owns the grid itself:
                scans: a background prepare thread generates traces, stages
                them sharded, and resolves each group's compiled executable
                (CompileCache: AOT executables keyed by the compile-signature
-               digest, optionally backed by jax's persistent compilation
-               cache so resumed/repeated processes skip XLA entirely) up to
+               digest, backed by jax's persistent compilation cache so
+               resumed/repeated processes skip XLA entirely) up to
                `prefetch_depth` groups ahead of retirement, recycling pooled
                host staging buffers instead of reallocating per group
                (fleet-state buffers are donated, so device memory is bounded
@@ -59,7 +59,6 @@ from typing import Any, Iterator, Mapping
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -74,6 +73,7 @@ from repro.launch.sharding import batch_shardings
 from repro.sim import trace as trace_mod
 from repro.sim.config import MachineConfig
 from repro.sim.runner import SimMetrics, finalize_metrics, totals_from_stats
+from repro.utils.compile_cache import enable_compile_cache
 
 Tags = tuple[tuple[str, Any], ...]
 
@@ -294,6 +294,15 @@ def plan_groups(plan: SweepPlan) -> list[FleetGroup]:
     ]
 
 
+def _shard_fleet(body, mesh):
+    """shard_map of a vmapped fleet body over the 1-D "fleet" mesh axis."""
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P("fleet"), P("fleet")), out_specs=(P("fleet"), P("fleet")),
+        check_vma=False,  # cells are independent, no collectives: nothing to check
+    )
+
+
 @functools.lru_cache(maxsize=None)
 def _sharded_fused_fn(spec: simloop.EngineSpec, intervals: int, mesh):
     """shard_map of the fused-generation engine body over the fleet mesh.
@@ -303,13 +312,8 @@ def _sharded_fused_fn(spec: simloop.EngineSpec, intervals: int, mesh):
     scan, so the only staged inputs are the (tiny) seed vector and initial
     fleet states — nothing for the double buffer to generate host-side.
     """
-    fn = shard_map(
-        simloop.batch_run_fused(spec, intervals),
-        mesh=mesh,
-        in_specs=(P("fleet"), P("fleet")),
-        out_specs=(P("fleet"), P("fleet")),
-    )
-    return jax.jit(fn, donate_argnums=(0,))
+    return jax.jit(_shard_fleet(simloop.batch_run_fused(spec, intervals), mesh),
+                   donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -323,56 +327,13 @@ def _sharded_fleet_fn(spec: simloop.EngineSpec, mesh):
     buffers are instead recycled when the group retires and the host drops its
     reference, bounding double-buffer memory at two staged groups.
     """
-    fn = shard_map(
-        simloop.batch_run(spec),
-        mesh=mesh,
-        in_specs=(P("fleet"), P("fleet")),
-        out_specs=(P("fleet"), P("fleet")),
-    )
-    return jax.jit(fn, donate_argnums=(0,))
+    return jax.jit(_shard_fleet(simloop.batch_run(spec), mesh),
+                   donate_argnums=(0,))
 
 
 # ---------------------------------------------------------------------------
 # Compile caching: skip retracing/re-XLA for repeated compile signatures
 # ---------------------------------------------------------------------------
-
-#: Point this env var at a directory to persist compiled fleet programs across
-#: processes (resumed sweeps, repeated atlas runs): see
-#: enable_persistent_compile_cache.
-PERSISTENT_CACHE_ENV = "REPRO_FLEET_CACHE_DIR"
-_persistent_cache_dir: str | None = None
-
-
-def enable_persistent_compile_cache(path=None) -> str | None:
-    """Back jax's compilation cache with an on-disk directory.
-
-    `path` (or the REPRO_FLEET_CACHE_DIR env var when None) names a directory
-    where XLA executables are persisted keyed by program fingerprint — a
-    superset of the fleet compile signature, so a resumed or repeated sweep
-    in a FRESH process skips the XLA compile of every signature it has seen
-    before (the dominant cost of cold atlas-scale plans). Returns the active
-    directory, or None when unset (no-op). Thresholds are dropped to zero so
-    even fast-compiling groups persist.
-    """
-    global _persistent_cache_dir
-    path = path if path is not None else os.environ.get(PERSISTENT_CACHE_ENV)
-    if not path:
-        return None
-    path = str(path)
-    if _persistent_cache_dir != path:
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # jax initializes its cache handle at most once, on the FIRST compile
-        # of the process — which import-time jitted constants usually trigger
-        # long before any runner exists, permanently latching "no cache
-        # configured". Reset so the next compile re-reads the directory.
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-        _persistent_cache_dir = path
-    return path
-
 
 def group_signature(group: FleetGroup, fleet_size: int, mesh) -> str:
     """Digest of everything determining one group's compiled fleet program.
@@ -395,9 +356,9 @@ class CompileCache:
     and shardings of its staged inputs and compiles it ahead of dispatch
     (jax.jit(...).lower(...).compile() — bit-identical to calling the jitted
     function, donation included). Repeated signatures across groups, plans,
-    and runs of one process hit `_exes`; with
-    enable_persistent_compile_cache, cache misses still skip the XLA backend
-    work in any process that compiled the signature before.
+    and runs of one process hit `_exes`; through the persistent compile
+    cache (repro.utils.compile_cache), cache misses still skip the XLA
+    backend work in any process that compiled the signature before.
 
     Thread-safe for the runner's single prepare thread + any number of
     readers; a module-level instance (COMPILE_CACHE) is shared by default so
@@ -442,11 +403,7 @@ class CompileCache:
             body = simloop.batch_run_fused(group.spec, group.intervals)
         else:
             body = simloop.batch_run(group.spec)
-        jitted = jax.jit(
-            shard_map(body, mesh=mesh, in_specs=(P("fleet"), P("fleet")),
-                      out_specs=(P("fleet"), P("fleet"))),
-            donate_argnums=(0,),
-        )
+        jitted = jax.jit(_shard_fleet(body, mesh), donate_argnums=(0,))
         sds = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                            sharding=x.sharding),
@@ -459,6 +416,25 @@ class CompileCache:
             self.compile_seconds += dt
             exe = self._exes.setdefault(sig, exe)
         return exe, sig, dt, False
+
+
+def group_metrics(group: FleetGroup, counters, stats) -> dict[SweepCell, SimMetrics]:
+    """Per-cell SimMetrics of one group's fleet outputs (final sim counters
+    and per-interval stats, fleet axis leading); padding lanes are dropped."""
+    stats_h = jax.tree.map(np.asarray, stats)
+    counters_h = jax.tree.map(np.asarray, counters)
+    out = {}
+    for i, cell in enumerate(group.cells):
+        per_cell = type(stats)(*(x[i] for x in stats_h))
+        totals = totals_from_stats(
+            cell.policy, cell.mc, per_cell, group.meta["accesses_per_interval"],
+        )
+        per_counters = type(counters)(*(x[i] for x in counters_h))
+        out[cell] = finalize_metrics(
+            cell.app, cell.policy, cell.mc, totals, per_counters,
+            group.meta["inst_per_access"], group.meta["footprint_pages"],
+        )
+    return out
 
 
 #: Process-wide default cache; pass `compile_cache=` to FleetRunner to isolate.
@@ -772,11 +748,10 @@ class FleetRunner:
     compile_cache   CompileCache instance (default: the process-wide
                     COMPILE_CACHE, so sequential runners share compiles).
 
-    Construction also arms jax's persistent compilation cache when
-    REPRO_FLEET_CACHE_DIR is set (enable_persistent_compile_cache), so
-    resumed or repeated sweeps in fresh processes skip XLA for every
-    signature compiled before. After a run, `timings` holds one GroupTiming
-    per retired group.
+    Construction also arms jax's persistent compilation cache
+    (repro.utils.compile_cache), so resumed or repeated sweeps in fresh
+    processes skip XLA for every signature compiled before. After a run,
+    `timings` holds one GroupTiming per retired group.
     """
 
     def __init__(self, mesh=None, double_buffer: bool = True, *,
@@ -795,7 +770,7 @@ class FleetRunner:
         self.compile_cache = compile_cache or COMPILE_CACHE
         self.timings: list[GroupTiming] = []
         self._staging_pool = _StagingPool()
-        enable_persistent_compile_cache()
+        enable_compile_cache()
 
     @property
     def double_buffer(self) -> bool:
@@ -935,19 +910,7 @@ class FleetRunner:
         """Block on one group's device results and finalize per-cell metrics."""
         if _mesh_is_multiprocess(self.mesh):
             counters, stats = _replicate_fn(self.mesh)((counters, stats))
-        stats_h = jax.tree.map(np.asarray, stats)
-        counters_h = jax.tree.map(np.asarray, counters)
-        for i, cell in enumerate(group.cells):  # padding lanes are dropped
-            per_cell = type(stats)(*(x[i] for x in stats_h))
-            totals = totals_from_stats(
-                cell.policy, cell.mc, per_cell,
-                group.meta["accesses_per_interval"],
-            )
-            per_counters = type(counters)(*(x[i] for x in counters_h))
-            out[cell] = finalize_metrics(
-                cell.app, cell.policy, cell.mc, totals, per_counters,
-                group.meta["inst_per_access"], group.meta["footprint_pages"],
-            )
+        out.update(group_metrics(group, counters, stats))
 
     # -- the sweep ----------------------------------------------------------
 

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import TPUCompilerParams
 
 
 def _kernel(src_ref, dst_ref, cap_ref, hot_in_ref, hot_out_ref):
@@ -33,7 +32,8 @@ def block_gather(
     hot: jax.Array,  # [HOT, block, KVS, hd]
     src: jax.Array,  # int32[K] (-1 = skip lane)
     dst: jax.Array,  # int32[K]
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     kk = src.shape[0]
     nhot = hot.shape[0]
@@ -59,6 +59,6 @@ def block_gather(
         out_shape=jax.ShapeDtypeStruct(hot_padded.shape, hot.dtype),
         interpret=interpret,
         input_output_aliases={3: 0},  # hot_padded -> out (untouched rows keep)
-        compiler_params=TPUCompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
     )(src_safe, dst_safe, cap, hot_padded)
     return out[:nhot]

@@ -16,7 +16,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import TPUCompilerParams
 
 NEG_INF = -2.0e38
 
@@ -74,7 +73,8 @@ def flash_attention(
     causal: bool = True,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     b, s, h, hd = q.shape
     bq = min(bq, s)
@@ -97,7 +97,7 @@ def flash_attention(
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
     )(q, k, v)

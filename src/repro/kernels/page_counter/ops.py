@@ -3,27 +3,24 @@ from __future__ import annotations
 
 import jax
 
-from repro.kernels.page_counter.page_counter import two_stage_count
-from repro.kernels.page_counter.ref import two_stage_count_ref
+from repro.kernels.page_counter.page_counter import (
+    fused_observe_count,
+    two_stage_count,
+)
+from repro.kernels.page_counter.ref import (
+    fused_observe_count_ref,
+    two_stage_count_ref,
+)
 
 
-def _kernel_mode(sp, force) -> str:
-    """Resolve the backend; zero-access chunks always take the ref oracle.
-
-    Pallas cannot slice a zero-length operand (grid of zero A-tiles), and an
-    empty interval's histograms are exactly the ref scatter's zeros — so the
-    TPU-default flip keeps working for degenerate chunks.
-    """
-    mode = force or ("pallas" if jax.default_backend() == "tpu" else "ref")
-    if sp.shape[0] == 0:
-        return "ref"
-    return mode
+def _kernel_mode(force) -> str:
+    return force or ("pallas" if jax.default_backend() == "tpu" else "ref")
 
 
 def count_accesses(
     sp, page, weight, monitored, num_superpages, pages_per_sp, force=None
 ):
-    mode = _kernel_mode(sp, force)
+    mode = _kernel_mode(force)
     if mode in ("pallas", "interpret"):
         return two_stage_count(
             sp, page, weight, monitored, num_superpages, pages_per_sp,
@@ -41,13 +38,11 @@ def observe_counts(
     """Fused one-pass observe histograms: (s1, s2_reads, s2_writes).
 
     The MemoryEngine's counting step (engine.control.observe_tiers) dispatches
-    here when `counter_backend` != "jax": "pallas" on TPU, "interpret" for the
-    Pallas interpreter, "ref" for the pure-jnp oracle.
+    here when `counter_backend` != "jax": "pallas" compiles the kernel for the
+    TPU, "interpret" runs it in the Pallas interpreter, "ref" is the pure-jnp
+    oracle.
     """
-    from repro.kernels.page_counter.page_counter import fused_observe_count
-    from repro.kernels.page_counter.ref import fused_observe_count_ref
-
-    mode = _kernel_mode(sp, force)
+    mode = _kernel_mode(force)
     if mode in ("pallas", "interpret"):
         return fused_observe_count(
             sp, page, is_write, monitored, num_superpages, pages_per_sp,

@@ -1,13 +1,17 @@
 """Two-stage access counter — Pallas TPU kernel (paper §III-B in hardware).
 
-The memory-controller counting path as a tiled streaming kernel: accesses arrive
-in VMEM tiles of A_TILE; both counter tables live in VMEM scratch across the
-grid (they are small by design — that is the paper's point: O(mem/2MB) + N*1KB)
-and are flushed to HBM on the last tile.
+The memory-controller counting path as a tiled streaming kernel. The access
+vectors are laid out as `(rows, lanes)` tiles and the grid walks them one
+`(8, lanes)` block at a time, the (8, 128) tiling the TPU's vector memory
+asks for. The counter tables are the kernel's outputs: their blocks never
+move across the grid, so they stay in VMEM and are written back to HBM once.
+They are small by design — that is the paper's point: O(mem/2MB) + N*1KB.
 
-Scatter-adds inside a tile are expressed as one-hot matmuls — the MXU-friendly
+Scatter-adds inside a row are expressed as one-hot matmuls — the MXU-friendly
 realization of "CAM + counter array" (TPU has no per-element atomic scatter;
-a [A_TILE, SP] one-hot times a ones-vector IS the histogram).
+a weight row times a [SP, lanes] one-hot IS the histogram). Each access row
+is broadcast along sublanes against an iota column, so no in-kernel
+transpose is needed. Counts accumulate in f32, which is exact below 2**24.
 """
 from __future__ import annotations
 
@@ -18,192 +22,116 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import TPUCompilerParams
+ROWS = 8  # sublanes per access block
 
 
-def _kernel(
-    monitored_ref,  # int32[N] (SMEM, scalar-prefetch)
-    sp_ref,  # int32[1, A_TILE]
-    page_ref,  # int32[1, A_TILE]
-    w_ref,  # f32[1, A_TILE]
-    s1_out,  # f32[NSP]
-    s2_out,  # f32[N, PAGES]
-    s1_acc,  # scratch f32[NSP]
-    s2_acc,  # scratch f32[N, PAGES]
-    *,
-    nsp: int,
-    pages: int,
-    n_mon: int,
-    tiles: int,
-):
-    t = pl.program_id(0)
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
-    @pl.when(t == 0)
-    def _init():
-        s1_acc[...] = jnp.zeros_like(s1_acc)
-        s2_acc[...] = jnp.zeros_like(s2_acc)
 
-    sp = sp_ref[0]
-    page = page_ref[0]
-    w = w_ref[0]
-    valid = sp >= 0
-    wv = jnp.where(valid, w, 0.0)
-
-    # stage 1: histogram over superpages via one-hot matmul
-    onehot = (sp[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, nsp), 1)).astype(
-        jnp.float32
-    )  # [A, NSP]
-    s1_acc[...] += jnp.einsum("an,a->n", onehot, wv)
-
-    # stage 2: monitored rows only
-    mon = monitored_ref[...]  # [N]
-    row_eq = (sp[:, None] == mon[None, :]) & (mon >= 0)[None, :]  # [A, N]
-    page_oh = (
-        page[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, pages), 1)
-    ).astype(jnp.float32)  # [A, PAGES]
-    contrib = jnp.einsum(
-        "an,ap->np", row_eq.astype(jnp.float32) * wv[:, None], page_oh
+def _dot_nt(a, b):
+    """a[M, L] . b[K, L]^T -> f32[M, K], exact for the integer counts here."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
-    s2_acc[...] += contrib
 
-    @pl.when(t == tiles - 1)
-    def _flush():
-        s1_out[...] = s1_acc[...]
-        s2_out[...] = s2_acc[...]
+
+def _kernel(mon_ref, sp_ref, page_ref, x_ref, s1_ref, *s2_refs, weights):
+    """One (ROWS, lanes) access block into s1[1, NSP] and each s2[N, P].
+
+    `weights(valid, x)` maps one row of the per-access operand to its
+    stage-1 weights and one weight row per stage-2 table, all f32[1, lanes].
+    """
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        s1_ref[...] = jnp.zeros_like(s1_ref)
+        for ref in s2_refs:
+            ref[...] = jnp.zeros_like(ref)
+
+    lanes = sp_ref.shape[1]
+    mon = mon_ref[...]  # int32[N, 1]
+    sp_iota = jax.lax.broadcasted_iota(jnp.int32, (s1_ref.shape[1], lanes), 0)
+    page_iota = jax.lax.broadcasted_iota(jnp.int32, (s2_refs[0].shape[1], lanes), 0)
+
+    def row(r, carry):
+        sp = sp_ref[pl.ds(r, 1), :]  # int32[1, lanes]
+        page = page_ref[pl.ds(r, 1), :]
+        w1, w2 = weights(sp >= 0, x_ref[pl.ds(r, 1), :])
+        # stage 1: histogram over superpages
+        sp_oh = (sp_iota == sp).astype(jnp.float32)  # [NSP, lanes]
+        s1_ref[...] += _dot_nt(w1, sp_oh)
+        # stage 2: monitored rows only
+        hit = ((mon == sp) & (mon >= 0)).astype(jnp.float32)  # [N, lanes]
+        page_oh = (page_iota == page).astype(jnp.float32)  # [P, lanes]
+        for ref, w in zip(s2_refs, w2):
+            ref[...] += _dot_nt(hit * w, page_oh)
+        return carry
+
+    jax.lax.fori_loop(0, ROWS, row, 0)
+
+
+def _count(sp, page, x, monitored, num_superpages, pages_per_sp, weights,
+           n_tables, lanes, interpret):
+    """Tile the accesses, run the kernel, return uint32 (s1, *s2 tables)."""
+    block = ROWS * lanes
+    tiles = max(-(-sp.shape[0] // block), 1)
+    pad = tiles * block - sp.shape[0]
+    sp = jnp.pad(sp.astype(jnp.int32), (0, pad), constant_values=-1)
+    page = jnp.pad(page.astype(jnp.int32), (0, pad))
+    x = jnp.pad(x, (0, pad))
+    n_mon = monitored.shape[0]
+    n_pad = _round_up(n_mon, ROWS)
+    nsp_pad = _round_up(num_superpages, 128)
+    p_pad = _round_up(pages_per_sp, 128)
+    mon = jnp.pad(monitored.astype(jnp.int32), (0, n_pad - n_mon),
+                  constant_values=-1).reshape(n_pad, 1)
+
+    access_spec = pl.BlockSpec((ROWS, lanes), lambda t: (t, 0))
+    outs = pl.pallas_call(
+        functools.partial(_kernel, weights=weights),
+        grid=(tiles,),
+        in_specs=[pl.BlockSpec((n_pad, 1), lambda t: (0, 0))] + [access_spec] * 3,
+        out_specs=[pl.BlockSpec((1, nsp_pad), lambda t: (0, 0))]
+        + [pl.BlockSpec((n_pad, p_pad), lambda t: (0, 0))] * n_tables,
+        out_shape=[jax.ShapeDtypeStruct((1, nsp_pad), jnp.float32)]
+        + [jax.ShapeDtypeStruct((n_pad, p_pad), jnp.float32)] * n_tables,
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+    )(mon, *(v.reshape(tiles * ROWS, lanes) for v in (sp, page, x)))
+    s1 = outs[0][0, :num_superpages]
+    s2 = [o[:n_mon, :pages_per_sp] for o in outs[1:]]
+    return tuple(t.astype(jnp.uint32) for t in (s1, *s2))
 
 
 @functools.partial(
-    jax.jit, static_argnames=("num_superpages", "pages_per_sp", "a_tile", "interpret")
+    jax.jit, static_argnames=("num_superpages", "pages_per_sp", "lanes", "interpret")
 )
 def two_stage_count(
-    sp: jax.Array,
-    page: jax.Array,
-    weight: jax.Array,
-    monitored: jax.Array,
+    sp: jax.Array,  # int32[A] superpage per access (-1 = skip)
+    page: jax.Array,  # int32[A]
+    weight: jax.Array,  # uint32[A]
+    monitored: jax.Array,  # int32[N] monitored superpage ids (-1 = unused row)
     num_superpages: int,
     pages_per_sp: int,
-    a_tile: int = 512,
-    interpret: bool = True,
-) -> tuple[jax.Array, jax.Array]:
-    a = sp.shape[0]
-    tiles = (a + a_tile - 1) // a_tile
-    pad = tiles * a_tile - a
-    if pad:
-        sp = jnp.pad(sp, (0, pad), constant_values=-1)
-        page = jnp.pad(page, (0, pad))
-        weight = jnp.pad(weight, (0, pad))
-    n_mon = monitored.shape[0]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(tiles,),
-        in_specs=[
-            pl.BlockSpec((1, a_tile), lambda t, mon: (t, 0)),
-            pl.BlockSpec((1, a_tile), lambda t, mon: (t, 0)),
-            pl.BlockSpec((1, a_tile), lambda t, mon: (t, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((num_superpages,), lambda t, mon: (0,)),
-            pl.BlockSpec((n_mon, pages_per_sp), lambda t, mon: (0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((num_superpages,), jnp.float32),
-            pltpu.VMEM((n_mon, pages_per_sp), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _kernel, nsp=num_superpages, pages=pages_per_sp, n_mon=n_mon, tiles=tiles
-    )
-    s1, s2 = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((num_superpages,), jnp.float32),
-            jax.ShapeDtypeStruct((n_mon, pages_per_sp), jnp.float32),
-        ],
-        interpret=interpret,
-        compiler_params=TPUCompilerParams(dimension_semantics=("arbitrary",)),
-    )(
-        monitored.astype(jnp.int32),
-        sp.reshape(tiles, a_tile),
-        page.reshape(tiles, a_tile),
-        weight.astype(jnp.float32).reshape(tiles, a_tile),
-    )
-    return s1.astype(jnp.uint32), s2.astype(jnp.uint32)
-
-
-# ---------------------------------------------------------------------------
-# Fused observe kernel: stage-1 (weighted) + stage-2 read/write histograms in
-# ONE pass over an access batch — the counting step of engine.control's
-# observe_tiers. Three counter tables ride in VMEM scratch across the grid and
-# flush on the last tile, so each access element is read exactly once.
-# ---------------------------------------------------------------------------
-
-
-def _fused_kernel(
-    monitored_ref,  # int32[N] (SMEM, scalar-prefetch)
-    sp_ref,  # int32[1, A_TILE]
-    page_ref,  # int32[1, A_TILE]
-    wr_ref,  # int32[1, A_TILE] is_write as 0/1
-    s1_out,  # f32[NSP]
-    s2r_out,  # f32[N, PAGES]
-    s2w_out,  # f32[N, PAGES]
-    s1_acc,  # scratch f32[NSP]
-    s2r_acc,  # scratch f32[N, PAGES]
-    s2w_acc,  # scratch f32[N, PAGES]
     *,
-    nsp: int,
-    pages: int,
-    write_weight: int,
-    tiles: int,
-):
-    t = pl.program_id(0)
+    lanes: int = 512,
+    interpret: bool,
+) -> tuple[jax.Array, jax.Array]:
+    """Weighted histograms: (s1 u32[NSP], s2 u32[N, P])."""
+    def weights(valid, w):
+        w = jnp.where(valid, w, 0.0)
+        return w, (w,)
 
-    @pl.when(t == 0)
-    def _init():
-        s1_acc[...] = jnp.zeros_like(s1_acc)
-        s2r_acc[...] = jnp.zeros_like(s2r_acc)
-        s2w_acc[...] = jnp.zeros_like(s2w_acc)
-
-    sp = sp_ref[0]
-    page = page_ref[0]
-    is_write = wr_ref[0] > 0
-    valid = sp >= 0
-
-    # per-lane weights: stage-1 counts writes heavier (§III-B); stage-2 keeps
-    # reads and writes in separate tables for the Eq. 1 utility split.
-    w1 = jnp.where(valid, jnp.where(is_write, float(write_weight), 1.0), 0.0)
-    w_r = jnp.where(valid & ~is_write, 1.0, 0.0)
-    w_w = jnp.where(valid & is_write, 1.0, 0.0)
-
-    # stage 1: histogram over superpages via one-hot matmul
-    onehot = (sp[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, nsp), 1)).astype(
-        jnp.float32
-    )  # [A, NSP]
-    s1_acc[...] += jnp.einsum("an,a->n", onehot, w1)
-
-    # stage 2: monitored rows only, read/write split
-    mon = monitored_ref[...]  # [N]
-    row_eq = ((sp[:, None] == mon[None, :]) & (mon >= 0)[None, :]).astype(
-        jnp.float32
-    )  # [A, N]
-    page_oh = (
-        page[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, pages), 1)
-    ).astype(jnp.float32)  # [A, PAGES]
-    s2r_acc[...] += jnp.einsum("an,ap->np", row_eq * w_r[:, None], page_oh)
-    s2w_acc[...] += jnp.einsum("an,ap->np", row_eq * w_w[:, None], page_oh)
-
-    @pl.when(t == tiles - 1)
-    def _flush():
-        s1_out[...] = s1_acc[...]
-        s2r_out[...] = s2r_acc[...]
-        s2w_out[...] = s2w_acc[...]
+    return _count(sp, page, weight.astype(jnp.float32), monitored,
+                  num_superpages, pages_per_sp, weights, 1, lanes, interpret)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "num_superpages", "pages_per_sp", "write_weight", "a_tile", "interpret",
+        "num_superpages", "pages_per_sp", "write_weight", "lanes", "interpret",
     ),
 )
 def fused_observe_count(
@@ -214,60 +142,23 @@ def fused_observe_count(
     num_superpages: int,
     pages_per_sp: int,
     write_weight: int = 2,
-    a_tile: int = 512,
-    interpret: bool = True,
+    *,
+    lanes: int = 512,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """One-pass batch histograms: (s1 u32[NSP], s2_reads, s2_writes u32[N, P])."""
-    a = sp.shape[0]
-    tiles = (a + a_tile - 1) // a_tile
-    pad = tiles * a_tile - a
-    wr = is_write.astype(jnp.int32)
-    if pad:
-        sp = jnp.pad(sp, (0, pad), constant_values=-1)
-        page = jnp.pad(page, (0, pad))
-        wr = jnp.pad(wr, (0, pad))
-    n_mon = monitored.shape[0]
+    """One-pass batch histograms: (s1 u32[NSP], s2_reads, s2_writes u32[N, P]).
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(tiles,),
-        in_specs=[
-            pl.BlockSpec((1, a_tile), lambda t, mon: (t, 0)),
-            pl.BlockSpec((1, a_tile), lambda t, mon: (t, 0)),
-            pl.BlockSpec((1, a_tile), lambda t, mon: (t, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((num_superpages,), lambda t, mon: (0,)),
-            pl.BlockSpec((n_mon, pages_per_sp), lambda t, mon: (0, 0)),
-            pl.BlockSpec((n_mon, pages_per_sp), lambda t, mon: (0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((num_superpages,), jnp.float32),
-            pltpu.VMEM((n_mon, pages_per_sp), jnp.float32),
-            pltpu.VMEM((n_mon, pages_per_sp), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _fused_kernel,
-        nsp=num_superpages,
-        pages=pages_per_sp,
-        write_weight=write_weight,
-        tiles=tiles,
-    )
-    s1, s2r, s2w = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((num_superpages,), jnp.float32),
-            jax.ShapeDtypeStruct((n_mon, pages_per_sp), jnp.float32),
-            jax.ShapeDtypeStruct((n_mon, pages_per_sp), jnp.float32),
-        ],
-        interpret=interpret,
-        compiler_params=TPUCompilerParams(dimension_semantics=("arbitrary",)),
-    )(
-        monitored.astype(jnp.int32),
-        sp.reshape(tiles, a_tile),
-        page.reshape(tiles, a_tile),
-        wr.reshape(tiles, a_tile),
-    )
-    return s1.astype(jnp.uint32), s2r.astype(jnp.uint32), s2w.astype(jnp.uint32)
+    The counting step of engine.control's observe_tiers: stage 1 counts
+    writes `write_weight` times heavier (§III-B); stage 2 keeps reads and
+    writes in separate tables for the Eq. 1 utility split. Each access
+    element is read once.
+    """
+    def weights(valid, wr):
+        is_write = wr > 0
+        w1 = jnp.where(valid, jnp.where(is_write, float(write_weight), 1.0), 0.0)
+        w_r = jnp.where(valid & ~is_write, 1.0, 0.0)
+        w_w = jnp.where(valid & is_write, 1.0, 0.0)
+        return w1, (w_r, w_w)
+
+    return _count(sp, page, is_write.astype(jnp.int32), monitored,
+                  num_superpages, pages_per_sp, weights, 2, lanes, interpret)

@@ -22,7 +22,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import TPUCompilerParams
 
 NEG_INF = -2.0e38
 
@@ -97,7 +96,8 @@ def rainbow_attention(
     pool_v: jax.Array,
     vidx: jax.Array,  # int32[B, nblk]
     length: jax.Array,  # int32 scalar
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     b, hp, hd = q.shape
     nblk = vidx.shape[1]
@@ -128,7 +128,7 @@ def rainbow_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hp, hd), q.dtype),
         interpret=interpret,
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
     )(vidx, jnp.reshape(length, (1,)).astype(jnp.int32), q, pool_k, pool_v)

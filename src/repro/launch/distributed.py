@@ -143,7 +143,7 @@ def initialize(
         _force_local_devices(env.local_devices)
     set_collectives = collectives and not xla_bridge.backends_are_initialized()
     if set_collectives:
-        prev = xla_bridge.CPU_COLLECTIVES_IMPLEMENTATION.value
+        prev = jax.config.jax_cpu_collectives_implementation
         jax.config.update("jax_cpu_collectives_implementation", collectives)
     try:
         if env is None:

@@ -44,6 +44,10 @@ from repro.train.step import (
 )
 
 
+#: The chip the production mesh stands for; its peaks give the roofline terms.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
 def _cost_get(cost: dict, key: str) -> float:
     if not cost:
         return 0.0
@@ -77,6 +81,7 @@ def run_cell(
         "shape": shape_id,
         "mesh": "2x16x16" if multi_pod else "16x16",
         "chips": int(chips),
+        "device_kind": TARGET_DEVICE_KIND,
         "kind": shape.kind,
         "attn_impl": attn_impl,
         "kv_impl": kv_impl,
@@ -287,7 +292,9 @@ def run_cell(
 
     flops_dev = _cost_get(cost, "flops")
     bytes_dev = _cost_get(cost, "bytes accessed")
-    terms = hlo_analysis.roofline_terms(flops_dev, bytes_dev, coll.total_bytes)
+    terms = hlo_analysis.roofline_terms(
+        flops_dev, bytes_dev, coll.total_bytes, device_kind=TARGET_DEVICE_KIND
+    )
     mflops = hlo_analysis.model_flops(cfg, shape, shape.kind)
     useful_ratio = mflops / (flops_dev * chips) if flops_dev else 0.0
 

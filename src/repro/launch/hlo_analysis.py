@@ -88,22 +88,38 @@ def collective_bytes(hlo_text: str) -> CollectiveStats:
 
 
 # ---------------------------------------------------------------------------
-# Roofline terms (TPU v5e-class constants from the assignment)
+# Roofline terms: published per-chip peaks, keyed by jax's device_kind
 # ---------------------------------------------------------------------------
 
-PEAK_FLOPS = 197e12  # bf16 FLOP/s per chip
-HBM_BW = 819e9  # B/s per chip
-ICI_BW = 50e9  # B/s per link
+#: Google Cloud TPU documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+#: 819 GB/s, 1,600 Gbit/s of interconnect over 4 links (50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict[str, float]:
+    """Peak bf16 FLOP/s, HBM B/s and ICI B/s per link of one chip."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
 
 
 def roofline_terms(
     flops_per_device: float,
     bytes_per_device: float,
     collective_bytes_per_device: float,
+    *,
+    device_kind: str,
 ) -> dict[str, float]:
-    compute_s = flops_per_device / PEAK_FLOPS
-    memory_s = bytes_per_device / HBM_BW
-    collective_s = collective_bytes_per_device / ICI_BW
+    pk = peaks(device_kind)
+    compute_s = flops_per_device / pk["flops"]
+    memory_s = bytes_per_device / pk["hbm_bw"]
+    collective_s = collective_bytes_per_device / pk["ici_bw"]
     terms = {"compute_s": compute_s, "memory_s": memory_s, "collective_s": collective_s}
     dom = max(terms, key=terms.get)
     terms["dominant"] = dom

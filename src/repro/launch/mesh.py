@@ -9,16 +9,23 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    """Mesh whose axes are all Auto: the model code places data with
+    sharding constraints, which JAX's default Explicit axes refuse."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(devices: int | None = None, model: int = 1):
     """Small mesh over available devices (for CPU integration tests)."""
     n = devices or len(jax.devices())
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def make_fleet_mesh(devices: int | None = None, *, processes: int | None = None):
@@ -40,7 +47,7 @@ def make_fleet_mesh(devices: int | None = None, *, processes: int | None = None)
 
         distributed.ensure_initialized(processes)
     n = devices or len(jax.devices())
-    return jax.make_mesh((n,), ("fleet",))
+    return _auto_mesh((n,), ("fleet",))
 
 
 def mesh_dp_size(mesh) -> int:

@@ -13,6 +13,7 @@ searches (interval_steps, threshold_init) engine-in-the-loop against it
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -25,6 +26,7 @@ from repro.models import model as M
 from repro.serving.rainbow_decode import rainbow_decode_step, record_mass_trace
 from repro.serving.steps import greedy_sample
 from repro.timing import GEOMETRY_PRESETS, get_geometry
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def resolve_timing(args, error):
@@ -55,33 +57,68 @@ def resolve_timing(args, error):
     return "queueing", geom
 
 
-def build_paged_config(args, nblk: int) -> PagedConfig:
-    """One PagedConfig from (preset, CLI overrides, geometry-aware defaults).
+def build_paged_config(nblk: int, block_size: int = 8,
+                       policy: str = "serving-default", **knobs) -> PagedConfig:
+    """One PagedConfig from (preset, overrides, geometry-aware defaults).
 
-    Precedence: explicit CLI flags > the chosen --policy preset. Geometry-aware
-    fallbacks (hot pool sized to the sequence) only apply to the generic
-    "serving-default" preset — a named preset's knobs are exactly what its
-    author registered.
+    `knobs` are the CLI's hot_slots / top_n / max_promotions /
+    interval_steps; None leaves the preset's value. Precedence: explicit
+    knobs > the chosen preset. Geometry-aware fallbacks (hot pool sized to
+    the sequence) only apply to the generic "serving-default" preset — a
+    named preset's knobs are exactly what its author registered.
     """
-    policy = get_policy(args.policy)
-    overrides = {
-        k: v for k, v in {
-            "hot_slots": args.hot_slots,
-            "top_n": args.top_n,
-            "max_promotions": args.max_promotions,
-            "interval_steps": args.interval_steps,
-        }.items() if v is not None
-    }
-    if args.policy == "serving-default":
+    preset = get_policy(policy)
+    overrides = {k: v for k, v in knobs.items() if v is not None}
+    if policy == "serving-default":
         hot = overrides.get("hot_slots", max(8, nblk // 2))
         overrides.setdefault("hot_slots", hot)
         overrides.setdefault("top_n", min(8, nblk))
         overrides.setdefault("max_promotions", min(16, hot))
     return PagedConfig(
-        block_size=args.block_size,
+        block_size=block_size,
         blocks_per_seq=nblk,
-        policy=policy.replace(**overrides) if overrides else policy,
+        policy=preset.replace(**overrides) if overrides else preset,
     )
+
+
+@dataclasses.dataclass(frozen=True)
+class Generation:
+    """One batched greedy decode."""
+
+    tokens: jax.Array  # int32[B, new]: the greedy continuation
+    logits: jax.Array  # f32[B, new, V]: the logits each new token came from
+    promoted: int | None  # hot KV blocks promoted (paged cache only)
+    seconds: float  # wall time, compilation included
+
+
+def generate(cfg, params, prompt: jax.Array, new_tokens: int,
+             pcfg: PagedConfig | None = None) -> Generation:
+    """Greedy decode after `prompt` over the flat KV cache, or over the
+    Rainbow-paged cache when `pcfg` is given."""
+    enable_compile_cache()
+    b, plen = prompt.shape
+    t0 = time.perf_counter()
+    if pcfg is None:
+        cache = M.init_cache(cfg, b, plen + new_tokens, tp=1)
+        logits, cache = M.prefill(cfg, params, {"tokens": prompt}, cache, tp=1)
+        logits = logits[:, -1:]
+        step = jax.jit(lambda p, t, c: M.decode_step(cfg, p, t, c))
+    else:
+        cache = paged_init(cfg, pcfg, b, 1, cfg.num_layers)
+        step = jax.jit(lambda p, t, k: rainbow_decode_step(cfg, pcfg, p, t, k))
+        # paged path consumes the prompt token-by-token (prefill-by-decode)
+        for i in range(plen):
+            logits, cache = step(params, prompt[:, i:i + 1], cache)
+    tokens, seen = [], []
+    for i in range(new_tokens):
+        if i:
+            logits, cache = step(params, tokens[-1], cache)
+        seen.append(logits[:, -1])
+        tokens.append(greedy_sample(logits, cfg.vocab_size))
+    out = jax.block_until_ready(
+        (jnp.concatenate(tokens, axis=1), jnp.stack(seen, axis=1)))
+    promoted = None if pcfg is None else int((cache.remap.remap >= 0).sum())
+    return Generation(*out, promoted, time.perf_counter() - t0)
 
 
 def main() -> None:
@@ -152,21 +189,16 @@ def main() -> None:
     total = args.prompt_len + args.tokens
     prompt = jax.random.randint(key, (b, args.prompt_len), 0, cfg.vocab_size)
 
-    t0 = time.time()
-    if args.kv == "flat":
-        cache = M.init_cache(cfg, b, total, tp=1)
-        logits, cache = M.prefill(cfg, params, {"tokens": prompt}, cache, tp=1)
-        step = jax.jit(lambda p, t, c: M.decode_step(cfg, p, t, c))
-        tok = greedy_sample(logits[:, -1:], cfg.vocab_size)
-        out = [tok]
-        for _ in range(args.tokens - 1):
-            logits, cache = step(params, tok, cache)
-            tok = greedy_sample(logits, cfg.vocab_size)
-            out.append(tok)
-    else:
+    pcfg = None
+    if args.kv == "paged":
         nblk = (total + args.block_size - 1) // args.block_size
         try:
-            pcfg = build_paged_config(args, nblk)
+            pcfg = build_paged_config(
+                nblk, args.block_size, args.policy,
+                hot_slots=args.hot_slots, top_n=args.top_n,
+                max_promotions=args.max_promotions,
+                interval_steps=args.interval_steps,
+            )
         except (ValueError, KeyError) as e:
             # impossible geometry / unknown preset -> clean CLI error
             ap.error(str(e.args[0]) if e.args else str(e))
@@ -207,25 +239,13 @@ def main() -> None:
                 policy=tuned,
             )
 
-        kv = paged_init(cfg, pcfg, b, 1, cfg.num_layers)
-        step = jax.jit(lambda p, t, k: rainbow_decode_step(cfg, pcfg, p, t, k))
-        # paged path consumes the prompt token-by-token (prefill-by-decode)
-        tok = prompt[:, :1]
-        for i in range(args.prompt_len):
-            logits, kv = step(params, prompt[:, i:i + 1], kv)
-        tok = greedy_sample(logits, cfg.vocab_size)
-        out = [tok]
-        for _ in range(args.tokens - 1):
-            logits, kv = step(params, tok, kv)
-            tok = greedy_sample(logits, cfg.vocab_size)
-            out.append(tok)
-        print(f"promoted hot blocks: {int((kv.remap.remap >= 0).sum())}")
-
-    toks = jnp.concatenate(out, axis=1)
-    dt = time.time() - t0
+    gen = generate(cfg, params, prompt, args.tokens, pcfg)
+    if gen.promoted is not None:
+        print(f"promoted hot blocks: {gen.promoted}")
+    dt = gen.seconds
     print(f"decoded {args.tokens} tokens x {b} seqs in {dt:.2f}s "
           f"({1000 * dt / args.tokens:.1f} ms/step incl. compile)")
-    print("first sequence:", toks[0].tolist()[:16], "...")
+    print("first sequence:", gen.tokens[0].tolist()[:16], "...")
 
 
 if __name__ == "__main__":

@@ -154,7 +154,10 @@ def embed_specs(cfg) -> Params:
 
 
 def embed_lookup(cfg, p: Params, tokens: jax.Array) -> jax.Array:
-    return p["tok"][tokens]
+    # rows follow the tokens' sharding, whatever the table's vocab sharding
+    sh = jax.typeof(tokens).sharding
+    out = None if sh.mesh.empty else sh.update(spec=P(*sh.spec, None))
+    return p["tok"].at[tokens].get(out_sharding=out)
 
 
 def lm_logits(cfg, p: Params, x: jax.Array) -> jax.Array:

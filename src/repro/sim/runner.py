@@ -238,7 +238,9 @@ def simulate(
             timing_model=timing_model, queue_geometry=queue_geometry,
         )
     from repro.engine import simloop  # lazy: sim.__init__ imports this module
+    from repro.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     mc = mc or MachineConfig()
     if fused:
         from repro.workloads import scenarios as scen

@@ -61,8 +61,10 @@ def test_sharded_train_step_on_4x2_mesh(tmp_path):
                                       build_train_step, init_train_state,
                                       train_state_specs)
 
+        from repro.launch.mesh import make_test_mesh
+
         cfg = get_reduced_config("qwen3-4b")  # 4 heads, kv 2 -> TP=2 works
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_test_mesh(model=2)  # (4, 2) over the 8 devices
         sc = make_constrainer(mesh)
         tcfg = TrainStepConfig(tp=2, remat="full")
         state = init_train_state(cfg, jax.random.PRNGKey(0), tcfg)

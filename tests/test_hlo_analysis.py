@@ -1,6 +1,7 @@
 """HLO-text collective parser + roofline terms (launch/hlo_analysis)."""
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.launch import hlo_analysis as H
 
@@ -45,9 +46,15 @@ def test_collective_bytes_on_real_compiled_module():
 
 
 def test_roofline_terms_dominance():
-    t = H.roofline_terms(197e12, 819e9 * 2, 0)  # 1 s compute, 2 s memory
+    # 1 s compute, 2 s memory on one v5e chip
+    t = H.roofline_terms(197e12, 819e9 * 2, 0, device_kind="TPU v5 lite")
     assert t["dominant"] == "memory_s"
     assert abs(t["roofline_fraction"] - 0.5) < 1e-6
+
+
+def test_roofline_terms_unknown_device_kind_is_an_error():
+    with pytest.raises(KeyError, match="no published peaks"):
+        H.roofline_terms(1.0, 1.0, 0, device_kind="cpu")
 
 
 def test_decode_bytes_global_sane():
