@@ -263,10 +263,15 @@ def interval_step(
 
     `jax.lax.scan(lambda st, tr: interval_step(cfg, st, *tr, timing), st, chunks)`
     runs an entire simulation device-resident — this is the EngineStep used by
-    engine.simloop's rainbow policy program.
+    engine.simloop's rainbow policy program. Its three phases run under the
+    named scopes "observe", "plan" and "apply" (engine.profile's phases).
     """
-    st = observe(cfg, st, sp, page, is_write, st.interval)
-    return end_interval(cfg, st, timing)
+    with jax.named_scope("observe"):
+        st = observe(cfg, st, sp, page, is_write, st.interval)
+    with jax.named_scope("plan"):
+        out = plan_interval(cfg, st, timing)
+    with jax.named_scope("apply"):
+        return apply_interval(cfg, st, out)
 
 
 def translate_accesses(

@@ -291,6 +291,9 @@ def nomad_interval(
     mc,
 ) -> tuple[NomadState, NomadReport]:
     """One full interval (observe batch + close), scannable — the nomad
-    counterpart of core.rainbow.interval_step."""
-    st = nomad_observe(cfg, st, sp, page, is_write, st.rb.interval)
-    return nomad_close(cfg, st, sp, page, is_write, timing, mc)
+    counterpart of core.rainbow.interval_step. The close runs under the
+    "plan" scope, as engine.profile times it."""
+    with jax.named_scope("observe"):
+        st = nomad_observe(cfg, st, sp, page, is_write, st.rb.interval)
+    with jax.named_scope("plan"):
+        return nomad_close(cfg, st, sp, page, is_write, timing, mc)

@@ -431,7 +431,8 @@ def _rainbow_migrate(spec: EngineSpec, pol, chunk):
     pol, rep = rb.interval_step(
         cfg, pol, chunk.sp, chunk.page, chunk.is_write, machine_timing(spec.mc)
     )
-    stats, inval = _rainbow_finish(spec, rep)
+    with jax.named_scope("apply"):
+        stats, inval = _rainbow_finish(spec, rep)
     return pol, stats, inval
 
 
@@ -471,7 +472,8 @@ def _nomad_migrate(spec: EngineSpec, pol, chunk):
         cfg, pol, chunk.sp, chunk.page, chunk.is_write,
         machine_timing(spec.mc), spec.mc,
     )
-    stats, inval = _nomad_finish(spec, rep)
+    with jax.named_scope("plan"):
+        stats, inval = _nomad_finish(spec, rep)
     return pol, stats, inval, (rep.bulk_dram, rep.bulk_nvm)
 
 
@@ -617,11 +619,17 @@ def _access_scan(
 def engine_step(
     spec: EngineSpec, state: EngineState, chunk: TraceChunks
 ) -> tuple[EngineState, IntervalStats]:
-    """One interval, device-resident: residency -> access scan -> migrate."""
+    """One interval, device-resident: residency -> access scan -> migrate.
+
+    Each phase runs under the `jax.named_scope` that engine.profile names
+    its phase program after (tlb, observe, plan, apply, queue), so the ops
+    of the fused program carry the phase in their op_name metadata.
+    """
     policy = spec.policy
-    in_dram = _residency(spec, state, chunk)
-    t0 = state.sim.t  # access clock BEFORE this interval's walk
-    sim = _access_scan(spec, state.sim, chunk, in_dram)
+    with jax.named_scope("tlb"):
+        in_dram = _residency(spec, state, chunk)
+        t0 = state.sim.t  # access clock BEFORE this interval's walk
+        sim = _access_scan(spec, state.sim, chunk, in_dram)
 
     inval = None
     bulk = None
@@ -630,25 +638,29 @@ def engine_step(
     elif policy == "nomad":
         pol, stats, inval, bulk = _nomad_migrate(spec, state.pol, chunk)
     elif policy == "hscc-4kb-mig":
-        pol, stats, inval = _hscc4k_migrate(spec, state.pol, chunk)
+        with jax.named_scope("plan"):
+            pol, stats, inval = _hscc4k_migrate(spec, state.pol, chunk)
     elif policy == "hscc-2mb-mig":
-        pol, stats, _ = _hscc2m_migrate(spec, state.pol, chunk)
+        with jax.named_scope("plan"):
+            pol, stats, _ = _hscc2m_migrate(spec, state.pol, chunk)
     else:
         pol, stats = state.pol, _zero_stats()
     if inval is not None:
-        sim = _invalidate_4k(sim, inval, spec.fastpath)
+        with jax.named_scope("apply"):
+            sim = _invalidate_4k(sim, inval, spec.fastpath)
     q = state.q
     geom = spec.timing_geometry()
     if geom is not None:
         extra = {} if bulk is None else {
             "bulk_dram": bulk[0], "bulk_nvm": bulk[1],
         }
-        q, tm = qtiming.interval_step(
-            geom, spec.mc, policy, state.q,
-            chunk.vpn, chunk.is_write, in_dram, t0,
-            stats.migrations, stats.evictions, stats.dirty_evictions,
-            **extra,
-        )
+        with jax.named_scope("queue"):
+            q, tm = qtiming.interval_step(
+                geom, spec.mc, policy, state.q,
+                chunk.vpn, chunk.is_write, in_dram, t0,
+                stats.migrations, stats.evictions, stats.dirty_evictions,
+                **extra,
+            )
         stats = stats._replace(
             stall_dram=tm.stall_dram,
             stall_nvm=tm.stall_nvm,
@@ -830,10 +842,13 @@ def _fused_scan(
     """
     setup, emit = _fused_program(spec)
     seed = jnp.asarray(seed, jnp.int32)
-    aux = setup(seed)
+    with jax.named_scope("synth"):
+        aux = setup(seed)
 
     def body(st, i):
-        return engine_step(spec, st, synth_chunk(spec, emit, aux, seed, i))
+        with jax.named_scope("synth"):
+            chunk = synth_chunk(spec, emit, aux, seed, i)
+        return engine_step(spec, st, chunk)
 
     return jax.lax.scan(body, state, jnp.arange(intervals, dtype=jnp.int32))
 

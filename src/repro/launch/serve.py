@@ -94,30 +94,44 @@ class Generation:
 def generate(cfg, params, prompt: jax.Array, new_tokens: int,
              pcfg: PagedConfig | None = None) -> Generation:
     """Greedy decode after `prompt` over the flat KV cache, or over the
-    Rainbow-paged cache when `pcfg` is given."""
+    Rainbow-paged cache when `pcfg` is given.
+
+    The host work sits in profiler spans on the device trace's clock:
+    "serve.init" (cache and step set-up), one "serve.step" step annotation
+    per prompt and decode step, and "serve.readback" (the final wait and the
+    promoted-block count)."""
     enable_compile_cache()
     b, plen = prompt.shape
     t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation("serve.init"):
+        if pcfg is None:
+            cache = M.init_cache(cfg, b, plen + new_tokens, tp=1)
+            step = jax.jit(lambda p, t, c: M.decode_step(cfg, p, t, c))
+        else:
+            cache = paged_init(cfg, pcfg, b, 1, cfg.num_layers)
+            step = jax.jit(lambda p, t, k: rainbow_decode_step(cfg, pcfg, p, t, k))
     if pcfg is None:
-        cache = M.init_cache(cfg, b, plen + new_tokens, tp=1)
-        logits, cache = M.prefill(cfg, params, {"tokens": prompt}, cache, tp=1)
-        logits = logits[:, -1:]
-        step = jax.jit(lambda p, t, c: M.decode_step(cfg, p, t, c))
+        with jax.profiler.StepTraceAnnotation("serve.step", step_num=0):
+            logits, cache = M.prefill(cfg, params, {"tokens": prompt}, cache, tp=1)
+            logits = logits[:, -1:]
+        first = 1
     else:
-        cache = paged_init(cfg, pcfg, b, 1, cfg.num_layers)
-        step = jax.jit(lambda p, t, k: rainbow_decode_step(cfg, pcfg, p, t, k))
         # paged path consumes the prompt token-by-token (prefill-by-decode)
         for i in range(plen):
-            logits, cache = step(params, prompt[:, i:i + 1], cache)
+            with jax.profiler.StepTraceAnnotation("serve.step", step_num=i):
+                logits, cache = step(params, prompt[:, i:i + 1], cache)
+        first = plen
     tokens, seen = [], []
     for i in range(new_tokens):
-        if i:
-            logits, cache = step(params, tokens[-1], cache)
-        seen.append(logits[:, -1])
-        tokens.append(greedy_sample(logits, cfg.vocab_size))
-    out = jax.block_until_ready(
-        (jnp.concatenate(tokens, axis=1), jnp.stack(seen, axis=1)))
-    promoted = None if pcfg is None else int((cache.remap.remap >= 0).sum())
+        with jax.profiler.StepTraceAnnotation("serve.step", step_num=first + i):
+            if i:
+                logits, cache = step(params, tokens[-1], cache)
+            seen.append(logits[:, -1])
+            tokens.append(greedy_sample(logits, cfg.vocab_size))
+    with jax.profiler.TraceAnnotation("serve.readback"):
+        out = jax.block_until_ready(
+            (jnp.concatenate(tokens, axis=1), jnp.stack(seen, axis=1)))
+        promoted = None if pcfg is None else int((cache.remap.remap >= 0).sum())
     return Generation(*out, promoted, time.perf_counter() - t0)
 
 

@@ -140,7 +140,14 @@ def rainbow_decode_step(
     scales: dict | None = None,  # int8 mode (pcfg.quantize): scale side pytree
     collect_mass: bool = False,  # also return this step's [B, nblk] block mass
 ):
-    """One decode step for a dense-family LM over the Rainbow paged cache."""
+    """One decode step for a dense-family LM over the Rainbow paged cache.
+
+    Its phases run under named scopes, so each op of the step names its
+    phase in its op_name metadata: "translate" (pool indices and the sparse
+    read set); per layer "qkv", "read" (the translated pool gather), "attend"
+    and "mlp"; then "append", "observe" (the controller's block mass),
+    "promote" (end of interval) and "logits".
+    """
     assert cfg.family in ("dense", "vlm"), "rainbow decode targets dense-family archs"
     b = tokens.shape[0]
     cur = kv.length
@@ -152,15 +159,16 @@ def rainbow_decode_step(
     seg_params = params["segments"][seg.name]
 
     # Translation is layer-invariant: compute the virtual pool indices once.
-    resident, vidx = pool_indices(kv, pcfg, b)
+    with jax.named_scope("translate"):
+        resident, vidx = pool_indices(kv, pcfg, b)
 
-    if mode == "sparse":
-        read_idx, read_valid, read_block = sparse_read_set(
-            kv, pcfg, b, precomputed=(resident, vidx)
-        )
-    else:
-        read_idx = vidx
-        read_valid = None
+        if mode == "sparse":
+            read_idx, read_valid, read_block = sparse_read_set(
+                kv, pcfg, b, precomputed=(resident, vidx)
+            )
+        else:
+            read_idx = vidx
+            read_valid = None
 
     def body(carry, xs):
         h = carry
@@ -168,55 +176,59 @@ def rainbow_decode_step(
             pl, cap_k_l, cap_v_l, hot_k_l, hot_v_l, csk, csv, hsk, hsv = xs
         else:
             pl, cap_k_l, cap_v_l, hot_k_l, hot_v_l = xs
-        hn = L.apply_norm(cfg, pl["ln1"], h)
-        q, k_new, v_new = attn.qkv_project(cfg, pl["attn"], hn, pos, use_rope=True)
+        with jax.named_scope("qkv"):
+            hn = L.apply_norm(cfg, pl["ln1"], h)
+            q, k_new, v_new = attn.qkv_project(cfg, pl["attn"], hn, pos, use_rope=True)
 
-        pool_k = jnp.concatenate([cap_k_l, hot_k_l], axis=0)
-        pool_v = jnp.concatenate([cap_v_l, hot_v_l], axis=0)
-        kvs_, hd = pool_k.shape[-2], pool_k.shape[-1]
-        if pcfg.quantize:
-            sk_pool = jnp.concatenate([csk, hsk], axis=0)
-            sv_pool = jnp.concatenate([csv, hsv], axis=0)
-            k_r = dequantize_kv(pool_k[read_idx], sk_pool[read_idx], x.dtype)
-            v_r = dequantize_kv(pool_v[read_idx], sv_pool[read_idx], x.dtype)
-            k_r = k_r.reshape(b, -1, kvs_, hd)
-            v_r = v_r.reshape(b, -1, kvs_, hd)
-        else:
-            k_r = pool_k[read_idx].reshape(b, -1, kvs_, hd)
-            v_r = pool_v[read_idx].reshape(b, -1, kvs_, hd)
-        k_r = jnp.concatenate([k_r, k_new], axis=1)  # fresh token attends itself
-        v_r = jnp.concatenate([v_r, v_new], axis=1)
+        with jax.named_scope("read"):
+            pool_k = jnp.concatenate([cap_k_l, hot_k_l], axis=0)
+            pool_v = jnp.concatenate([cap_v_l, hot_v_l], axis=0)
+            kvs_, hd = pool_k.shape[-2], pool_k.shape[-1]
+            if pcfg.quantize:
+                sk_pool = jnp.concatenate([csk, hsk], axis=0)
+                sv_pool = jnp.concatenate([csv, hsv], axis=0)
+                k_r = dequantize_kv(pool_k[read_idx], sk_pool[read_idx], x.dtype)
+                v_r = dequantize_kv(pool_v[read_idx], sv_pool[read_idx], x.dtype)
+                k_r = k_r.reshape(b, -1, kvs_, hd)
+                v_r = v_r.reshape(b, -1, kvs_, hd)
+            else:
+                k_r = pool_k[read_idx].reshape(b, -1, kvs_, hd)
+                v_r = pool_v[read_idx].reshape(b, -1, kvs_, hd)
+            k_r = jnp.concatenate([k_r, k_new], axis=1)  # fresh token attends itself
+            v_r = jnp.concatenate([v_r, v_new], axis=1)
 
         smax = k_r.shape[1]
-        if mode == "sparse":
-            token_ok = jnp.repeat(read_valid, pcfg.block_size, axis=1)
-            valid = jnp.concatenate(
-                [token_ok, jnp.ones((b, 1), bool)], axis=1
-            )  # fresh token always readable
-            o, lane_mass = _attend_with_mass(
-                q, k_r, v_r, valid, pcfg.block_size, read_idx.shape[1]
-            )
-            # Scatter read-lane mass back to home blocks so the controller
-            # observes sparse reads too (lanes are deduplicated, so each
-            # block's mass lands exactly once; invalid lanes drop). Without
-            # this, sparse mode fed zero mass to observe_block_mass, nothing
-            # ever promoted, and a hot block leaving the trailing window was
-            # lost forever — the promotion-rejoin path existed only in full
-            # mode.
-            dest = jnp.where(read_block >= 0, read_block, nblk)
-            blk_mass = jnp.zeros((b, nblk), jnp.float32).at[
-                jnp.arange(b)[:, None], dest
-            ].add(lane_mass, mode="drop")
-        else:
-            pos_ids = jnp.arange(smax)
-            valid = (pos_ids < cur) | (pos_ids == smax - 1)  # history + fresh
-            o, blk_mass = _attend_with_mass(
-                q, k_r, v_r, valid, pcfg.block_size, nblk
-            )
+        with jax.named_scope("attend"):
+            if mode == "sparse":
+                token_ok = jnp.repeat(read_valid, pcfg.block_size, axis=1)
+                valid = jnp.concatenate(
+                    [token_ok, jnp.ones((b, 1), bool)], axis=1
+                )  # fresh token always readable
+                o, lane_mass = _attend_with_mass(
+                    q, k_r, v_r, valid, pcfg.block_size, read_idx.shape[1]
+                )
+                # Scatter read-lane mass back to home blocks so the controller
+                # observes sparse reads too (lanes are deduplicated, so each
+                # block's mass lands exactly once; invalid lanes drop). Without
+                # this, sparse mode fed zero mass to observe_block_mass,
+                # nothing ever promoted, and a hot block leaving the trailing
+                # window was lost forever — the promotion-rejoin path existed
+                # only in full mode.
+                dest = jnp.where(read_block >= 0, read_block, nblk)
+                blk_mass = jnp.zeros((b, nblk), jnp.float32).at[
+                    jnp.arange(b)[:, None], dest
+                ].add(lane_mass, mode="drop")
+            else:
+                pos_ids = jnp.arange(smax)
+                valid = (pos_ids < cur) | (pos_ids == smax - 1)  # history + fresh
+                o, blk_mass = _attend_with_mass(
+                    q, k_r, v_r, valid, pcfg.block_size, nblk
+                )
 
-        h = h + attn.attn_output(pl["attn"], o)
-        h2 = L.apply_norm(cfg, pl["ln2"], h)
-        h = h + L.apply_mlp(cfg, pl["mlp"], h2, sc=sc)
+        with jax.named_scope("mlp"):
+            h = h + attn.attn_output(pl["attn"], o)
+            h2 = L.apply_norm(cfg, pl["ln2"], h)
+            h = h + L.apply_mlp(cfg, pl["mlp"], h2, sc=sc)
         return h, (k_new[:, 0], v_new[:, 0], blk_mass)
 
     if pcfg.quantize:
@@ -224,38 +236,45 @@ def rainbow_decode_step(
               scales["cap_k"], scales["cap_v"], scales["hot_k"], scales["hot_v"])
     else:
         xs = (seg_params, kv.cap_k, kv.cap_v, kv.hot_k, kv.hot_v)
-    h, (k_all, v_all, mass_all) = jax.lax.scan(body, x, xs)
+    # "layers" names what the scan itself adds around the body's scopes: the
+    # per-layer slices of the stacked pools and what XLA fuses into them
+    with jax.named_scope("layers"):
+        h, (k_all, v_all, mass_all) = jax.lax.scan(body, x, xs)
 
-    if pcfg.quantize:
-        kv, scales = append_token_q8(kv, pcfg, scales, k_all, v_all)
-    else:
-        kv = append_token(kv, pcfg, None, k_all, v_all)
-    step_mass = mass_all.sum(axis=0)  # [B, nblk] — the controller's access stream
-    kv = observe_block_mass(kv, pcfg, step_mass)
+    with jax.named_scope("append"):
+        if pcfg.quantize:
+            kv, scales = append_token_q8(kv, pcfg, scales, k_all, v_all)
+        else:
+            kv = append_token(kv, pcfg, None, k_all, v_all)
+    with jax.named_scope("observe"):
+        step_mass = mass_all.sum(axis=0)  # [B, nblk] — the controller's access stream
+        kv = observe_block_mass(kv, pcfg, step_mass)
     kv = dataclasses.replace(kv, length=kv.length + 1)
 
-    if pcfg.quantize:
-        def do_promote(args):
-            kv_, sc_ = args
-            new, rep = end_interval_promote(kv_, pcfg)
-            sc_ = promote_scales(sc_, pcfg, rep["plan"], rep["cand_sp"], rep["cand_pg"])
-            return new, sc_
+    with jax.named_scope("promote"):
+        if pcfg.quantize:
+            def do_promote(args):
+                kv_, sc_ = args
+                new, rep = end_interval_promote(kv_, pcfg)
+                sc_ = promote_scales(sc_, pcfg, rep["plan"], rep["cand_sp"], rep["cand_pg"])
+                return new, sc_
 
-        kv, scales = jax.lax.cond(
-            kv.step_in_interval >= pcfg.interval_steps, do_promote,
-            lambda a: a, (kv, scales),
-        )
-    else:
-        def do_promote(kv_):
-            new, _ = end_interval_promote(kv_, pcfg)
-            return new
+            kv, scales = jax.lax.cond(
+                kv.step_in_interval >= pcfg.interval_steps, do_promote,
+                lambda a: a, (kv, scales),
+            )
+        else:
+            def do_promote(kv_):
+                new, _ = end_interval_promote(kv_, pcfg)
+                return new
 
-        kv = jax.lax.cond(
-            kv.step_in_interval >= pcfg.interval_steps, do_promote, lambda s: s, kv
-        )
+            kv = jax.lax.cond(
+                kv.step_in_interval >= pcfg.interval_steps, do_promote, lambda s: s, kv
+            )
 
-    h = L.apply_norm(cfg, params["final_norm"], h)
-    logits = L.lm_logits(cfg, params["embed"], h)
+    with jax.named_scope("logits"):
+        h = L.apply_norm(cfg, params["final_norm"], h)
+        logits = L.lm_logits(cfg, params["embed"], h)
     out = (logits, kv) + ((scales,) if pcfg.quantize else ())
     if collect_mass:
         out = out + (step_mass,)

@@ -22,6 +22,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
+import jax
 import numpy as np
 
 from repro.sim import trace as trace_mod
@@ -242,49 +243,56 @@ def simulate(
 
     enable_compile_cache()
     mc = mc or MachineConfig()
-    if fused:
-        from repro.workloads import scenarios as scen
+    # Host spans on the profiler's clock: set-up, the device run, host readback.
+    with jax.profiler.TraceAnnotation("sim.prepare"):
+        if fused:
+            from repro.workloads import scenarios as scen
 
-        if not scen.is_scenario(app):
-            raise ValueError(
-                f"fused generation needs a registered scenario, got {app!r} "
-                f"(registered: {scen.available_scenarios()}); numpy app "
-                "profiles/mixes run staged"
+            if not scen.is_scenario(app):
+                raise ValueError(
+                    f"fused generation needs a registered scenario, got {app!r} "
+                    f"(registered: {scen.available_scenarios()}); numpy app "
+                    "profiles/mixes run staged"
+                )
+            meta = trace_mod.probe_meta(app, accesses)
+            source = simloop.TraceSource(scenario=app, accesses=accesses)
+            chunks = None
+        else:
+            chunks, meta = simloop.make_chunks(
+                app, policy, mc, seed, intervals, accesses
             )
-        meta = trace_mod.probe_meta(app, accesses)
-        source = simloop.TraceSource(scenario=app, accesses=accesses)
-        chunks = None
-    else:
-        chunks, meta = simloop.make_chunks(
-            app, policy, mc, seed, intervals, accesses
+            source = None
+        spec = simloop.EngineSpec(
+            policy=policy,
+            mc=mc,
+            num_superpages=meta["num_superpages"],
+            footprint_pages=meta["footprint_pages"],
+            counter_backend=counter_backend,
+            source=source,
+            fastpath=fastpath,
+            timing_model=timing_model,
+            queue_geometry=queue_geometry,
         )
-        source = None
-    spec = simloop.EngineSpec(
-        policy=policy,
-        mc=mc,
-        num_superpages=meta["num_superpages"],
-        footprint_pages=meta["footprint_pages"],
-        counter_backend=counter_backend,
-        source=source,
-        fastpath=fastpath,
-        timing_model=timing_model,
-        queue_geometry=queue_geometry,
-    )
+        state0 = simloop.engine_init(spec)
     # The freshly built engine_init state is never reused, so its buffers are
     # donated to the scan — the carry updates in place instead of copying.
-    if fused:
-        state, stats = simloop.engine_run_fused(
-            spec, simloop.engine_init(spec), seed, intervals, donate=True
+    with jax.profiler.TraceAnnotation("sim.run"):
+        if fused:
+            state, stats = simloop.engine_run_fused(
+                spec, state0, seed, intervals, donate=True
+            )
+        else:
+            state, stats = simloop.engine_run(spec, state0, chunks, donate=True)
+        # the span ends with the device program, not with its dispatch
+        state, stats = jax.block_until_ready((state, stats))
+    with jax.profiler.TraceAnnotation("sim.finalize"):
+        totals = totals_from_stats(
+            policy, mc, stats, meta["accesses_per_interval"]
         )
-    else:
-        state, stats = simloop.engine_run(
-            spec, simloop.engine_init(spec), chunks, donate=True
+        return finalize_metrics(
+            app, policy, mc, totals, state.sim.counters,
+            meta["inst_per_access"], meta["footprint_pages"],
         )
-    totals = totals_from_stats(policy, mc, stats, meta["accesses_per_interval"])
-    return finalize_metrics(
-        app, policy, mc, totals, state.sim.counters,
-        meta["inst_per_access"], meta["footprint_pages"],
-    )
 
 
 def simulate_eager(

@@ -77,7 +77,8 @@ def make_access_step(kind: str, mc: MachineConfig):
     Returned step: (SimState, (vpn, sp, in_dram, is_write)) -> (SimState, None).
     `run_interval` scans it over one interval's accesses; engine.simloop embeds
     the same step inside its whole-simulation scan so the device-resident
-    engine is bit-identical to the host-looped path.
+    engine is bit-identical to the host-looped path. Each structure's lookup
+    runs under a named scope: "tlb4k", "tlb2m" and "bmc" (the bitmap cache).
     """
 
     l1l, l2l = mc.l1_tlb_lat, mc.l2_tlb_lat
@@ -91,7 +92,8 @@ def make_access_step(kind: str, mc: MachineConfig):
         mem_cost = jnp.where(wr, mem_wr, mem_rd)
 
         if kind == "flat4k":
-            tlb4, h1, h2 = split_tlb_lookup(st.tlb4, v, now)
+            with jax.named_scope("tlb4k"):
+                tlb4, h1, h2 = split_tlb_lookup(st.tlb4, v, now)
             walk = (~h1) & (~h2)
             c = _acc(
                 c,
@@ -108,7 +110,8 @@ def make_access_step(kind: str, mc: MachineConfig):
             return SimState(tlb4, st.tlb2m, st.bmc, now + 1, c), None
 
         if kind == "sp2m":
-            tlb2m, h1, h2 = split_tlb_lookup(st.tlb2m, s, now)
+            with jax.named_scope("tlb2m"):
+                tlb2m, h1, h2 = split_tlb_lookup(st.tlb2m, s, now)
             walk = (~h1) & (~h2)
             c = _acc(
                 c,
@@ -127,15 +130,18 @@ def make_access_step(kind: str, mc: MachineConfig):
         # ---- rainbow: Fig. 6 four cases ----
         # 4KB TLB holds only DRAM-cached pages; consulted in parallel with the
         # superpage TLB. Fill 4KB TLB only when the access resolves to DRAM.
-        tlb4, h41, h42 = split_tlb_lookup(st.tlb4, v, now, fill=dram)
+        with jax.named_scope("tlb4k"):
+            tlb4, h41, h42 = split_tlb_lookup(st.tlb4, v, now, fill=dram)
         hit4 = (h41 | h42) & dram  # stale-proof: entry implies residency
-        tlb2m, h21, h22 = split_tlb_lookup(st.tlb2m, s, now)
+        with jax.named_scope("tlb2m"):
+            tlb2m, h21, h22 = split_tlb_lookup(st.tlb2m, s, now)
         sp_hit = h21 | h22
         sptw = ~sp_hit
 
         # Cases 3/4: 4KB miss -> consult bitmap (cache) for the home superpage.
         need_bitmap = ~hit4
-        bmc, bmc_hit = bitmap_cache_lookup(st.bmc, s, now)
+        with jax.named_scope("bmc"):
+            bmc, bmc_hit = bitmap_cache_lookup(st.bmc, s, now)
         bmc_miss = need_bitmap & ~bmc_hit
         cost_bitmap = jnp.where(
             need_bitmap, mc.bitmap_cache_lat + jnp.where(bmc_miss, mc.t_nr, 0.0), 0.0
@@ -332,7 +338,8 @@ def make_interval_runner(kind: str, mc: MachineConfig, unroll: int = INTERVAL_UN
     (SimState, vpn, sp, in_dram, is_write) -> SimState, and bit-identical to
     it (tests/test_hotpath.py pins the equivalence property-wise; the
     engine-vs-eager suite pins it end-to-end). Memoized per (kind, mc) so jit
-    tracing caches see one function identity.
+    tracing caches see one function identity. The lookups carry the named
+    scopes of make_access_step.
     """
 
     l1l, l2l = mc.l1_tlb_lat, mc.l2_tlb_lat
@@ -356,11 +363,13 @@ def make_interval_runner(kind: str, mc: MachineConfig, unroll: int = INTERVAL_UN
             tlb0 = st.tlb4 if kind == "flat4k" else st.tlb2m
             key = vpn if kind == "flat4k" else sp
             walk_cost = walk4 if kind == "flat4k" else walk2m
+            scope = "tlb4k" if kind == "flat4k" else "tlb2m"
 
             def body(carry, xs):
                 tlb, t, ctlb, cwalk, cmem, m1, m2 = carry
                 v, mcost = xs
-                tlb, h1, h2 = _fused_split_lookup(tlb, v, t)
+                with jax.named_scope(scope):
+                    tlb, h1, h2 = _fused_split_lookup(tlb, v, t)
                 walk = (~h1) & (~h2)
                 ctlb = ctlb + (l1l + jnp.where(~h1, l2l, 0.0))
                 cwalk = cwalk + jnp.where(walk, walk_cost, 0.0)
@@ -397,12 +406,15 @@ def make_interval_runner(kind: str, mc: MachineConfig, unroll: int = INTERVAL_UN
         def body(carry, xs):
             tlb4, tlb2m, bmc, t, ctlb, cwalk, cbmp, crmp, cmem, m41, m42, m21, m22, mb = carry
             v, s, dram, mcost = xs
-            tlb4, h41, h42 = _fused_split_lookup(tlb4, v, t, fill=dram)
+            with jax.named_scope("tlb4k"):
+                tlb4, h41, h42 = _fused_split_lookup(tlb4, v, t, fill=dram)
             hit4 = (h41 | h42) & dram
-            tlb2m, h21, h22 = _fused_split_lookup(tlb2m, s, t)
+            with jax.named_scope("tlb2m"):
+                tlb2m, h21, h22 = _fused_split_lookup(tlb2m, s, t)
             sptw = ~(h21 | h22)
             need_bitmap = ~hit4
-            bmc, bmc_hit = _fast_bmc_lookup(bmc, s, t)
+            with jax.named_scope("bmc"):
+                bmc, bmc_hit = _fast_bmc_lookup(bmc, s, t)
             bmc_miss = need_bitmap & ~bmc_hit
             ctlb = ctlb + (l1l + jnp.where(~h41 & ~h21, l2l, 0.0))
             cwalk = cwalk + jnp.where(need_bitmap & sptw, walk2m, 0.0)
