@@ -1,20 +1,59 @@
-"""Jit'd dispatch wrapper: Pallas kernel on TPU, jnp oracle elsewhere."""
+"""Dispatch wrapper: Pallas kernel on TPU, jnp oracle elsewhere."""
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels.rainbow_attention.rainbow_attention import rainbow_attention
-from repro.kernels.rainbow_attention.ref import rainbow_attention_ref
+from repro.kernels.rainbow_attention.ref import paged_decode_attention_ref
+
+
+def backend(block: int, head_dim: int, force: str | None = None) -> str:
+    """The path paged_decode_attention takes: "pallas" on a TPU when K/V
+    blocks sit on the bf16 (16, 128) tile, else "ref". force: "pallas",
+    "interpret" or "ref" (tests)."""
+    if force:
+        return force
+    on_tile = block % 16 == 0 and head_dim % 128 == 0
+    return "pallas" if jax.default_backend() == "tpu" and on_tile else "ref"
+
+
+def _merge_fresh(q, k_new, v_new, m, l, acc, blk_m, blk_l):
+    """Fold the fresh token into the kernel's history softmax.
+
+    Returns the normalized output [B, HP, hd] (q's dtype) and the block mass
+    [B, nblk]: each block's share of the softmax summed over query heads, the
+    fresh token in the normalization and not in the mass."""
+    hd = q.shape[-1]
+    rep = q.shape[1] // k_new.shape[1]
+    kn = jnp.repeat(k_new, rep, axis=1)  # query head h reads kv head h // rep
+    vn = jnp.repeat(v_new, rep, axis=1)
+    s_new = jnp.einsum("bhk,bhk->bh", q, kn, preferred_element_type=jnp.float32)
+    s_new = s_new / np.sqrt(hd)
+    mf = jnp.maximum(m, s_new)
+    alpha, e_new = jnp.exp(m - mf), jnp.exp(s_new - mf)
+    lf = l * alpha + e_new
+    out = (acc * alpha[..., None] + e_new[..., None] * vn.astype(jnp.float32))
+    out = (out / lf[..., None]).astype(q.dtype)
+    mass = (blk_l * jnp.exp(blk_m - mf[..., None]) / lf[..., None]).sum(axis=1)
+    return out, mass
 
 
 def paged_decode_attention(
-    q, pool_k, pool_v, vidx, length, force: str | None = None
+    q, k_new, v_new, cap_k, cap_v, hot_k, hot_v, vidx, layer, length,
+    force: str | None = None,
 ):
-    """force: None (auto), "pallas", "interpret", "ref"."""
-    backend = jax.default_backend()
-    mode = force or ("pallas" if backend == "tpu" else "ref")
-    if mode == "pallas":
-        return rainbow_attention(q, pool_k, pool_v, vidx, length, interpret=False)
-    if mode == "interpret":
-        return rainbow_attention(q, pool_k, pool_v, vidx, length, interpret=True)
-    return rainbow_attention_ref(q, pool_k, pool_v, vidx, length)
+    """Decode attention of one token through the translated pools.
+
+    q [B, HP, hd]; k_new/v_new [B, KVS, hd]; stacked pools [L, n, block, KVS,
+    hd]; vidx int32[B, nblk] (see ref.py). Returns (out [B, HP, hd], block
+    mass f32[B, nblk])."""
+    mode = backend(cap_k.shape[2], q.shape[-1], force)
+    if mode == "ref":
+        return paged_decode_attention_ref(
+            q, k_new, v_new, cap_k, cap_v, hot_k, hot_v, vidx, layer, length)
+    with jax.named_scope("paged_attention"):
+        stats = rainbow_attention(q, cap_k, cap_v, hot_k, hot_v, vidx, layer,
+                                  length, interpret=mode == "interpret")
+    return _merge_fresh(q, k_new, v_new, *stats)

@@ -12,9 +12,11 @@ than the paper's post-LLC reference counts — adaptation note 3). Two-stage
 counting (core.counting) runs at superblock then block granularity; admission is
 the utility test (core.migration) with (HBM bw, host-link bw) timings.
 
-The pure-JAX read path realizes translation as ONE gather into a virtually
-concatenated [capacity ++ hot] pool — the TPU-idiomatic form of Fig. 6's
-indirection. kernels/rainbow_attention implements the same recurrence tiled.
+Translation yields one table per step: each block's capacity-pool home, or
+its hot-pool slot when resident (serving/rainbow_decode.pool_indices). On a
+TPU, kernels/rainbow_attention follows it block by block, DMAing only the
+live blocks of a layer from the stacked pool each lives in; the pure-JAX read
+path gathers through the layer's concatenated [capacity ++ hot] pool.
 """
 from __future__ import annotations
 
@@ -309,28 +311,6 @@ def promote_scales(scales: dict, pcfg: PagedConfig, plan, cand_sp, cand_pg) -> d
 
 def _replace(kv: RainbowKV, **kw) -> RainbowKV:
     return dataclasses.replace(kv, **kw)
-
-
-def gather_layer_kv(
-    kv: RainbowKV, pcfg: PagedConfig, layer: jax.Array, batch: int
-) -> tuple[jax.Array, jax.Array]:
-    """Translated read of one layer's KV: [B, blocks_per_seq, block, KVS, hd].
-
-    Single-gather translation: virtual pool = capacity ++ hot; resident blocks
-    redirect to num_cap + slot (Fig. 6 cases via one indirection).
-    """
-    nb = batch * pcfg.blocks_per_seq
-    blocks = jnp.arange(pcfg.blocks_per_seq)
-    seqs = jnp.arange(batch)
-    sp = seqs[:, None].repeat(pcfg.blocks_per_seq, 1)
-    pg = blocks[None, :].repeat(batch, 0)
-    resident, slot = translate(kv.remap, sp, pg)
-    home = (sp * pcfg.blocks_per_seq + pg).astype(jnp.int32)
-    vidx = jnp.where(resident, nb + slot, home)  # [B, blocks_per_seq]
-
-    pool_k = jnp.concatenate([kv.cap_k[layer], kv.hot_k[layer]], axis=0)
-    pool_v = jnp.concatenate([kv.cap_v[layer], kv.hot_v[layer]], axis=0)
-    return pool_k[vidx], pool_v[vidx]
 
 
 def quantize_mass(mass: jax.Array) -> jax.Array:

@@ -1,8 +1,13 @@
 """Rainbow-managed decode: paged KV with two-tier translation + hot-block stats.
 
 Read modes:
-  * full   — attend over every block through the translated (single-gather)
-             pool read; numerically identical to flat-cache decode.
+  * full   — attend over the whole history through the translation. On a TPU
+             with float pools and blocks on the bf16 (16, 128) tile, the
+             kernels/rainbow_attention kernel DMAs only the live blocks, each
+             from the pool it lives in; elsewhere one gather through the
+             layer's concatenated [capacity ++ hot] pool reads every block
+             and masks past the length (numerically identical to flat-cache
+             decode).
   * sparse — attend over hot-pool blocks + the trailing window only (stage-1
              screened). This is where tiering pays on real hardware: cold
              blocks stay in the capacity tier (host memory) untouched. The
@@ -28,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.remap import translate
+from repro.kernels.rainbow_attention import ops as ra_ops
 from repro.memory.kvcache import (
     PagedConfig,
     RainbowKV,
@@ -71,8 +77,10 @@ def _attend_with_mass(q, k, v, valid, block_size, nblk):
 def pool_indices(kv: RainbowKV, pcfg: PagedConfig, batch: int):
     """Layer-invariant translated pool indices: (resident[B, nblk], vidx[B, nblk]).
 
-    vidx indexes the virtually concatenated [capacity ++ hot] pool; resident
-    blocks redirect to num_cap + slot (Fig. 6 cases via one indirection).
+    vidx indexes a virtual [capacity ++ hot] pool: a block's capacity home,
+    or num_cap + slot when resident (Fig. 6 cases via one indirection). The
+    kernel path reads each block from the pool vidx names; the jnp path
+    gathers through the concatenation.
     """
     nblk = pcfg.blocks_per_seq
     blocks = jnp.arange(nblk)
@@ -146,7 +154,12 @@ def rainbow_decode_step(
     phase in its op_name metadata: "translate" (pool indices and the sparse
     read set); per layer "qkv", "read" (the translated pool gather), "attend"
     and "mlp"; then "append", "observe" (the controller's block mass),
-    "promote" (end of interval) and "logits".
+    "promote" (end of interval) and "logits". Where the rainbow_attention
+    kernel reads the pools (full mode, float pools, a TPU and on-tile shapes:
+    ops.backend decides), it runs as "attend/paged_attention" and there is
+    no "read"; the layer scan then carries only the parameters and the
+    layer index, and the kernel DMAs each live block of that layer from the
+    stacked pools.
     """
     assert cfg.family in ("dense", "vlm"), "rainbow decode targets dense-family archs"
     b = tokens.shape[0]
@@ -157,6 +170,8 @@ def rainbow_decode_step(
 
     seg = M.segments(cfg)[0]
     seg_params = params["segments"][seg.name]
+    kernel = (mode == "full" and not pcfg.quantize
+              and ra_ops.backend(pcfg.block_size, cfg.head_dim) != "ref")
 
     # Translation is layer-invariant: compute the virtual pool indices once.
     with jax.named_scope("translate"):
@@ -172,7 +187,9 @@ def rainbow_decode_step(
 
     def body(carry, xs):
         h = carry
-        if pcfg.quantize:
+        if kernel:
+            pl, layer = xs
+        elif pcfg.quantize:
             pl, cap_k_l, cap_v_l, hot_k_l, hot_v_l, csk, csv, hsk, hsv = xs
         else:
             pl, cap_k_l, cap_v_l, hot_k_l, hot_v_l = xs
@@ -180,50 +197,57 @@ def rainbow_decode_step(
             hn = L.apply_norm(cfg, pl["ln1"], h)
             q, k_new, v_new = attn.qkv_project(cfg, pl["attn"], hn, pos, use_rope=True)
 
-        with jax.named_scope("read"):
-            pool_k = jnp.concatenate([cap_k_l, hot_k_l], axis=0)
-            pool_v = jnp.concatenate([cap_v_l, hot_v_l], axis=0)
-            kvs_, hd = pool_k.shape[-2], pool_k.shape[-1]
-            if pcfg.quantize:
-                sk_pool = jnp.concatenate([csk, hsk], axis=0)
-                sv_pool = jnp.concatenate([csv, hsv], axis=0)
-                k_r = dequantize_kv(pool_k[read_idx], sk_pool[read_idx], x.dtype)
-                v_r = dequantize_kv(pool_v[read_idx], sv_pool[read_idx], x.dtype)
-                k_r = k_r.reshape(b, -1, kvs_, hd)
-                v_r = v_r.reshape(b, -1, kvs_, hd)
-            else:
-                k_r = pool_k[read_idx].reshape(b, -1, kvs_, hd)
-                v_r = pool_v[read_idx].reshape(b, -1, kvs_, hd)
-            k_r = jnp.concatenate([k_r, k_new], axis=1)  # fresh token attends itself
-            v_r = jnp.concatenate([v_r, v_new], axis=1)
+        if kernel:
+            with jax.named_scope("attend"):
+                o, blk_mass = ra_ops.paged_decode_attention(
+                    q[:, 0], k_new[:, 0], v_new[:, 0], kv.cap_k, kv.cap_v,
+                    kv.hot_k, kv.hot_v, read_idx, layer, cur)
+                o = o[:, None]
+        else:
+            with jax.named_scope("read"):
+                pool_k = jnp.concatenate([cap_k_l, hot_k_l], axis=0)
+                pool_v = jnp.concatenate([cap_v_l, hot_v_l], axis=0)
+                kvs_, hd = pool_k.shape[-2], pool_k.shape[-1]
+                if pcfg.quantize:
+                    sk_pool = jnp.concatenate([csk, hsk], axis=0)
+                    sv_pool = jnp.concatenate([csv, hsv], axis=0)
+                    k_r = dequantize_kv(pool_k[read_idx], sk_pool[read_idx], x.dtype)
+                    v_r = dequantize_kv(pool_v[read_idx], sv_pool[read_idx], x.dtype)
+                    k_r = k_r.reshape(b, -1, kvs_, hd)
+                    v_r = v_r.reshape(b, -1, kvs_, hd)
+                else:
+                    k_r = pool_k[read_idx].reshape(b, -1, kvs_, hd)
+                    v_r = pool_v[read_idx].reshape(b, -1, kvs_, hd)
+                k_r = jnp.concatenate([k_r, k_new], axis=1)  # fresh token attends itself
+                v_r = jnp.concatenate([v_r, v_new], axis=1)
 
-        smax = k_r.shape[1]
-        with jax.named_scope("attend"):
-            if mode == "sparse":
-                token_ok = jnp.repeat(read_valid, pcfg.block_size, axis=1)
-                valid = jnp.concatenate(
-                    [token_ok, jnp.ones((b, 1), bool)], axis=1
-                )  # fresh token always readable
-                o, lane_mass = _attend_with_mass(
-                    q, k_r, v_r, valid, pcfg.block_size, read_idx.shape[1]
-                )
-                # Scatter read-lane mass back to home blocks so the controller
-                # observes sparse reads too (lanes are deduplicated, so each
-                # block's mass lands exactly once; invalid lanes drop). Without
-                # this, sparse mode fed zero mass to observe_block_mass,
-                # nothing ever promoted, and a hot block leaving the trailing
-                # window was lost forever — the promotion-rejoin path existed
-                # only in full mode.
-                dest = jnp.where(read_block >= 0, read_block, nblk)
-                blk_mass = jnp.zeros((b, nblk), jnp.float32).at[
-                    jnp.arange(b)[:, None], dest
-                ].add(lane_mass, mode="drop")
-            else:
-                pos_ids = jnp.arange(smax)
-                valid = (pos_ids < cur) | (pos_ids == smax - 1)  # history + fresh
-                o, blk_mass = _attend_with_mass(
-                    q, k_r, v_r, valid, pcfg.block_size, nblk
-                )
+            smax = k_r.shape[1]
+            with jax.named_scope("attend"):
+                if mode == "sparse":
+                    token_ok = jnp.repeat(read_valid, pcfg.block_size, axis=1)
+                    valid = jnp.concatenate(
+                        [token_ok, jnp.ones((b, 1), bool)], axis=1
+                    )  # fresh token always readable
+                    o, lane_mass = _attend_with_mass(
+                        q, k_r, v_r, valid, pcfg.block_size, read_idx.shape[1]
+                    )
+                    # Scatter read-lane mass back to home blocks so the controller
+                    # observes sparse reads too (lanes are deduplicated, so each
+                    # block's mass lands exactly once; invalid lanes drop). Without
+                    # this, sparse mode fed zero mass to observe_block_mass,
+                    # nothing ever promoted, and a hot block leaving the trailing
+                    # window was lost forever — the promotion-rejoin path existed
+                    # only in full mode.
+                    dest = jnp.where(read_block >= 0, read_block, nblk)
+                    blk_mass = jnp.zeros((b, nblk), jnp.float32).at[
+                        jnp.arange(b)[:, None], dest
+                    ].add(lane_mass, mode="drop")
+                else:
+                    pos_ids = jnp.arange(smax)
+                    valid = (pos_ids < cur) | (pos_ids == smax - 1)  # history + fresh
+                    o, blk_mass = _attend_with_mass(
+                        q, k_r, v_r, valid, pcfg.block_size, nblk
+                    )
 
         with jax.named_scope("mlp"):
             h = h + attn.attn_output(pl["attn"], o)
@@ -231,13 +255,16 @@ def rainbow_decode_step(
             h = h + L.apply_mlp(cfg, pl["mlp"], h2, sc=sc)
         return h, (k_new[:, 0], v_new[:, 0], blk_mass)
 
-    if pcfg.quantize:
+    if kernel:
+        xs = (seg_params, jnp.arange(kv.cap_k.shape[0], dtype=jnp.int32))
+    elif pcfg.quantize:
         xs = (seg_params, kv.cap_k, kv.cap_v, kv.hot_k, kv.hot_v,
               scales["cap_k"], scales["cap_v"], scales["hot_k"], scales["hot_v"])
     else:
         xs = (seg_params, kv.cap_k, kv.cap_v, kv.hot_k, kv.hot_v)
     # "layers" names what the scan itself adds around the body's scopes: the
-    # per-layer slices of the stacked pools and what XLA fuses into them
+    # per-layer slices of the stacked pools (none on the kernel path) and
+    # what XLA fuses into them
     with jax.named_scope("layers"):
         h, (k_all, v_all, mass_all) = jax.lax.scan(body, x, xs)
 

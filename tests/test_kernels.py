@@ -86,17 +86,33 @@ def _block_gather(nb, hot, k, dtype, all_invalid=False):
     return run
 
 
+def _paged_inputs(b, hp, kvs, hd, block, nblk, dtype, layers=2, seed=0):
+    """Stacked pools, a fresh token and a translated block table in which
+    about a third of the blocks are resident in distinct hot slots."""
+    ks = jax.random.split(jax.random.PRNGKey(seed * 131 + b * 7 + hp), 7)
+    ncap, nhot = b * nblk, b * nblk // 3 + 2
+    q = jax.random.normal(ks[0], (b, hp, hd), dtype)
+    k_new = jax.random.normal(ks[1], (b, kvs, hd), dtype)
+    v_new = jax.random.normal(ks[2], (b, kvs, hd), dtype)
+    pools = [jax.random.normal(k, (layers, n, block, kvs, hd), dtype)
+             for k, n in zip(ks[3:], (ncap, ncap, nhot, nhot))]
+    rng = np.random.default_rng(seed)
+    resident = np.zeros(ncap, bool)
+    resident[rng.choice(ncap, nhot - 2, replace=False)] = True
+    slot = np.full(ncap, -1)
+    slot[resident] = rng.permutation(nhot)[: nhot - 2]
+    vidx = np.where(resident, ncap + slot, np.arange(ncap)).reshape(b, nblk)
+    return q, k_new, v_new, pools, jnp.asarray(vidx, jnp.int32)
+
+
 def _rainbow_attention(b, hp, kvs, hd, block, nblk, dtype):
     def run(force, rng):
-        npool = b * nblk + 4
-        q = jax.random.normal(jax.random.PRNGKey(b * 7 + hp), (b, hp, hd), dtype)
-        pk = jax.random.normal(jax.random.PRNGKey(1), (npool, block, kvs, hd), dtype)
-        pv = jax.random.normal(jax.random.PRNGKey(2), (npool, block, kvs, hd), dtype)
-        vidx = jax.random.randint(jax.random.PRNGKey(3), (b, nblk), 0, npool)
+        q, kn, vn, pools, vidx = _paged_inputs(b, hp, kvs, hd, block, nblk, dtype)
         length = jnp.int32(max(nblk * block - 2, 1))
-        ref = paged_decode_attention(q, pk, pv, vidx, length, force="ref")
-        ker = paged_decode_attention(q, pk, pv, vidx, length, force=force)
-        return (ref,), (ker,), (2e-2 if dtype == jnp.bfloat16 else 2e-5)
+        args = (q, kn, vn, *pools, vidx, jnp.int32(1), length)
+        ref = paged_decode_attention(*args, force="ref")
+        ker = paged_decode_attention(*args, force=force)
+        return ref, ker, (2e-2 if dtype == jnp.bfloat16 else 2e-5)
 
     return run
 
@@ -178,3 +194,88 @@ def test_flash_attention_sweep(b, s, h, hd, causal, dtype):
     np.testing.assert_allclose(
         np.asarray(ker, np.float32), np.asarray(ref, np.float32), atol=tol, rtol=tol
     )
+
+
+# -- rainbow paged decode attention: what it reads and what it reports -------
+
+
+def _poison(pool, keep):
+    """NaN in every block of the stacked pool except `keep` (layer, block)."""
+    out = jnp.full_like(pool, jnp.nan)
+    for lyr, blk in keep:
+        out = out.at[lyr, blk].set(pool[lyr, blk])
+    return out
+
+
+def _read_case(name, dtype):
+    b, hp, kvs, hd, block, nblk, layer = 2, 8, 4, 32, 8, 8, 1
+    q, kn, vn, pools, vidx = _paged_inputs(b, hp, kvs, hd, block, nblk, dtype, seed=3)
+    cap_k, cap_v, hot_k, hot_v = pools
+    ncap = cap_k.shape[1]
+    v = np.asarray(vidx)
+    length = {"unread_blocks_nan": 11, "resident_reads_hot": nblk * block - 5,
+              "length_zero": 0, "partial_last_block": 2 * block + 3,
+              "mass_matches_step": 3 * block + 1}[name]
+    live = v[:, : -(-length // block)].reshape(-1)
+    if name == "unread_blocks_nan":
+        # length far below capacity: only the live blocks' copies of this
+        # layer that the table names are left readable
+        cap = [(layer, i) for i in live if i < ncap]
+        hot = [(layer, i - ncap) for i in live if i >= ncap]
+        cap_k, cap_v = _poison(cap_k, cap), _poison(cap_v, cap)
+        hot_k, hot_v = _poison(hot_k, hot), _poison(hot_v, hot)
+    elif name == "resident_reads_hot":
+        # a resident block's capacity copy is stale: the hot slot is read
+        home = np.arange(ncap).reshape(b, nblk)[v >= ncap]
+        cap_k = cap_k.at[layer, home].set(jnp.nan)
+        cap_v = cap_v.at[layer, home].set(jnp.nan)
+    elif name == "partial_last_block":
+        # positions past the length inside the last live block hold garbage
+        off = length % block
+        for i in v[:, length // block]:
+            if i >= ncap:
+                hot_k = hot_k.at[layer, i - ncap, off:].set(jnp.nan)
+                hot_v = hot_v.at[layer, i - ncap, off:].set(jnp.nan)
+            else:
+                cap_k = cap_k.at[layer, i, off:].set(jnp.nan)
+                cap_v = cap_v.at[layer, i, off:].set(jnp.nan)
+    args = (q, kn, vn, cap_k, cap_v, hot_k, hot_v, vidx, jnp.int32(layer),
+            jnp.int32(length))
+    return args, length, block
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=_dtype_tag)
+@pytest.mark.parametrize("name", [
+    "unread_blocks_nan", "resident_reads_hot", "length_zero",
+    "partial_last_block", "mass_matches_step",
+])
+def test_rainbow_attention_read_set(name, dtype):
+    args, length, block = _read_case(name, dtype)
+    out, mass = paged_decode_attention(*args, force="interpret")
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    if name == "mass_matches_step":
+        # the decode step's jnp path: gather through the concatenated pool,
+        # append the fresh token, attend with per-block mass
+        from repro.serving.rainbow_decode import _attend_with_mass
+
+        q, kn, vn, cap_k, cap_v, hot_k, hot_v, vidx, layer, _ = args
+        b, nblk = vidx.shape
+        pool_k = jnp.concatenate([cap_k[layer], hot_k[layer]])[vidx]
+        pool_v = jnp.concatenate([cap_v[layer], hot_v[layer]])[vidx]
+        k_r = jnp.concatenate([pool_k.reshape(b, nblk * block, *kn.shape[1:]),
+                               kn[:, None]], axis=1)
+        v_r = jnp.concatenate([pool_v.reshape(b, nblk * block, *vn.shape[1:]),
+                               vn[:, None]], axis=1)
+        pos = jnp.arange(k_r.shape[1])
+        ref_out, ref_mass = _attend_with_mass(
+            q[:, None], k_r, v_r, (pos < length) | (pos == nblk * block), block, nblk)
+        ref_out = ref_out[:, 0]
+    else:
+        ref_out, ref_mass = paged_decode_attention(*args, force="ref")
+    assert bool(jnp.isfinite(out).all()) and bool(jnp.isfinite(mass).all())
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref_out, np.float32), atol=tol, rtol=tol)
+    np.testing.assert_allclose(np.asarray(mass), np.asarray(ref_mass),
+                               atol=1e-5, rtol=1e-5)
+    # blocks past the length get exactly no mass
+    assert not np.asarray(mass)[:, -(-length // block):].any()

@@ -6,6 +6,7 @@ by these names, so a refactor that drops one shows here
 first. Lowering only: nothing is compiled or run.
 """
 import re
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +14,7 @@ import pytest
 
 from repro.configs import get_reduced_config
 from repro.engine import simloop
+from repro.kernels.rainbow_attention import ops as ra_ops
 from repro.memory.kvcache import PagedConfig, paged_init, paged_scales_init
 from repro.models import model as M
 from repro.serving.rainbow_decode import rainbow_decode_step
@@ -38,7 +40,11 @@ ENGINE_CASES = [
     ("flat-static", {"policy": "flat-static"}, {"synth", "tlb", "tlb4k"}),
 ]
 DECODE_CASES = [("decode-full", {}, DECODE), ("decode-sparse", {"mode": "sparse"}, DECODE),
-                ("decode-int8", {"quantize": True}, DECODE)]
+                ("decode-int8", {"quantize": True}, DECODE),
+                # where the rainbow_attention kernel reads the pools (a TPU picks
+                # it; forced here), it runs under "attend/paged_attention"
+                ("decode-full-kernel", {"kernel": True},
+                 DECODE - {"read"} | {"paged_attention"})]
 
 
 def scope_segments(lowered) -> set[str]:
@@ -73,6 +79,9 @@ def _decode(kw):
                                                                scales=x))
         return step.lower(params, tok, kv, sc)
     step = jax.jit(lambda p, t, k: rainbow_decode_step(cfg, pcfg, p, t, k, mode=mode))
+    if kw.get("kernel"):
+        with mock.patch.object(ra_ops, "backend", lambda *a, **k: "interpret"):
+            return step.lower(params, tok, kv)
     return step.lower(params, tok, kv)
 
 

@@ -43,6 +43,51 @@ def test_rainbow_decode_exact_vs_flat():
     assert bool(check_consistency(kv.remap))
 
 
+def test_rainbow_decode_kernel_path_matches_jnp(monkeypatch):
+    """The rainbow_attention kernel (interpret mode) in the decode step against
+    the jnp read, across promotions and evictions. Layer 0's K/V depend only on
+    the token, so its pools match bitwise; deeper layers' K/V see the layer
+    below through an attention output rounded to bf16 in another order, so
+    they match to bf16 rounding, as do the logits and the step mass."""
+    from repro.kernels.rainbow_attention import ops as ra_ops
+    from repro.serving.rainbow_decode import record_mass_trace
+
+    cfg, pcfg, params, toks, B, S = _setup()
+
+    def run():
+        step = jax.jit(lambda p, t, k: rainbow_decode_step(
+            cfg, pcfg, p, t, k, collect_mass=True))
+        kv = paged_init(cfg, pcfg, B, 1, cfg.num_layers)
+        out = []
+        for t in range(S):
+            logits, kv, mass = step(params, toks[:, t:t + 1], kv)
+            out.append((logits, kv, mass))
+        return out, record_mass_trace(cfg, pcfg, params, toks, S)
+
+    ref, (ref_trace, ref_kv) = run()
+    monkeypatch.setattr(ra_ops, "backend", lambda *a, **k: "interpret")
+    ker, (ker_trace, ker_kv) = run()
+
+    def close(a, b, tol):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), atol=tol, rtol=tol)
+
+    for (rl, rkv, rm), (kl, kkv, km) in zip(ref, ker):
+        close(kl, rl, 2e-2)
+        close(km, rm, 2e-2)
+        for name in ("cap_k", "cap_v", "hot_k", "hot_v"):
+            r, k = getattr(rkv, name), getattr(kkv, name)
+            np.testing.assert_array_equal(np.asarray(k[0]), np.asarray(r[0]))
+            close(k, r, 2e-2)
+        for r, k in zip(jax.tree.leaves((rkv.remap, rkv.dram, rkv.length)),
+                        jax.tree.leaves((kkv.remap, kkv.dram, kkv.length))):
+            np.testing.assert_array_equal(np.asarray(k), np.asarray(r))
+    assert int((ker[-1][1].remap.remap >= 0).sum()) > 0, "no promotions happened"
+    close(ker_trace.mass, ref_trace.mass, 2e-2)
+    np.testing.assert_array_equal(np.asarray(ker_kv.remap.remap),
+                                  np.asarray(ref_kv.remap.remap))
+
+
 def test_promotion_respects_hot_pool_capacity():
     cfg, pcfg, params, toks, B, S = _setup(interval_steps=2)
     rb_step = jax.jit(lambda p, t, k: rainbow_decode_step(cfg, pcfg, p, t, k))

@@ -7,7 +7,9 @@ compiles one kernel ahead of time and checks that the program really holds
 a Mosaic kernel (`tpu_custom_call`). The topology is described inside a
 fixture, so collecting this file never loads the TPU compiler.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +19,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels.block_gather.block_gather import block_gather
+from repro.kernels.rainbow_attention import ops as ra_ops
+from repro.kernels.rainbow_attention.rainbow_attention import rainbow_attention
 from repro.kernels.page_counter.page_counter import (
     fused_observe_count,
     two_stage_count,
@@ -27,6 +31,9 @@ from repro.workloads.scenarios import probe_meta
 ACCESSES = 320_000  # syn/GUPS's calibrated accesses per interval
 MONITORED = 100  # MachineConfig().top_n
 KV_BLOCK = 8  # launch.serve's --block-size
+# the paged decode cell: batch 8, 256 blocks of 16 tokens per sequence, a hot
+# pool of 128 blocks (build_paged_config(256, 16))
+DECODE_BATCH, DECODE_NBLK, DECODE_BLOCK, DECODE_HOT = 8, 256, 16, 128
 
 
 @pytest.fixture(scope="module")
@@ -76,12 +83,24 @@ def _block_gather_case(sds):
                 sds((lanes,), jnp.int32), sds((lanes,), jnp.int32))
 
 
+def _rainbow_attention_case(sds):
+    cfg = get_config("qwen3-0.6b")
+    blk = (DECODE_BLOCK, cfg.num_kv_heads, cfg.head_dim)
+    cap = sds((cfg.num_layers, DECODE_BATCH * DECODE_NBLK, *blk), jnp.bfloat16)
+    hot = sds((cfg.num_layers, DECODE_HOT, *blk), jnp.bfloat16)
+    fn = lambda *a: rainbow_attention(*a, interpret=False)  # noqa: E731
+    return fn, (sds((DECODE_BATCH, cfg.num_heads, cfg.head_dim), jnp.bfloat16),
+                cap, cap, hot, hot, sds((DECODE_BATCH, DECODE_NBLK), jnp.int32),
+                sds((), jnp.int32), sds((), jnp.int32))
+
+
 @pytest.mark.parametrize("case", [
     pytest.param(_counter_case(fused_observe_count, jnp.bool_),
                  id="fused_observe_count"),
     pytest.param(_counter_case(two_stage_count, jnp.uint32),
                  id="two_stage_count"),
     pytest.param(_block_gather_case, id="block_gather"),
+    pytest.param(_rainbow_attention_case, id="rainbow_attention"),
 ])
 def test_kernel_compiles_for_v5e(case, one_chip):
     def sds(shape, dtype):
@@ -90,3 +109,35 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     fn, args = case(sds)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The whole decode step at the cell's widths: the layer loop calls the
+    rainbow_attention kernel and holds no operation of pool scale — no
+    gather, conversion or broadcast over all 4,096 provisioned positions, no
+    per-layer slice or concatenation of the pools."""
+    from repro.launch.serve import build_paged_config
+    from repro.memory.kvcache import paged_init
+    from repro.models import model as M
+    from repro.serving.rainbow_decode import rainbow_decode_step
+
+    # on this CPU host ops.backend would pick the jnp read
+    monkeypatch.setattr(ra_ops, "backend", lambda *a, **k: "pallas")
+    cfg = dataclasses.replace(get_config("qwen3-0.6b"), tie_embeddings=True)
+    pcfg = build_paged_config(DECODE_NBLK, DECODE_BLOCK)
+    params = jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0), tp=1))
+    kv = jax.eval_shape(lambda: paged_init(cfg, pcfg, DECODE_BATCH, 1, cfg.num_layers))
+    on_chip = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    tokens = jax.ShapeDtypeStruct((DECODE_BATCH, 1), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, t, k: rainbow_decode_step(cfg, pcfg, p, t, k))
+    text = step.lower(on_chip(params), tokens, on_chip(kv)).compile().as_text()
+    assert "tpu_custom_call" in text
+    positions = DECODE_NBLK * DECODE_BLOCK
+    per_layer_pool = (DECODE_BATCH * DECODE_NBLK, DECODE_BLOCK, cfg.num_kv_heads,
+                      cfg.head_dim)
+    for shape in (f"[{DECODE_BATCH},{positions}", f"[{DECODE_BATCH},{positions + 1}",
+                  "[{},{},{},{}]".format(*per_layer_pool),
+                  "[{},{},{},{}]".format(per_layer_pool[0] + DECODE_HOT,
+                                         *per_layer_pool[1:])):
+        assert not re.search(r"\w+" + re.escape(shape), text), shape
