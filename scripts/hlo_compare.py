@@ -9,12 +9,16 @@ programs that the benchmark's cells run, at the cells' sizes:
                                  (`simloop._engine_run_fused_donated`) of the
                                  `gups` configuration, 3 intervals
   decode-step                    `rainbow_decode_step` of `qwen3-0.6b` at the
-                                 `decode-b8-p64-o192` traffic's cache size
+                                 `decode-b8-p64-o192` traffic's cache size,
+                                 as the CPU selects it (the jnp read)
+  decode-step-kernel             the same step with the rainbow_attention
+                                 kernel reading the pools, as on a TPU
 
 Each checkout is compiled in a process of its own (both hold the same module
 names). The HLO text is stripped of `metadata={...}` (op_name, source file
-and line) and of the stack-frame tables it points into; what is left is
-what the device runs. Prints one line per program: "identical",
+and line) and of the stack-frame tables it points into, and each Mosaic
+kernel's serialized body is replaced by the SHA-256 of its MLIR printed
+without source locations; what is left is what the device runs. Prints one line per program: "identical",
 "identical up to instruction names" (the same text once every `%name` is
 renumbered in order of appearance), or "DIFFERENT" with the first lines of
 the diff. Exits 1 if any program differs. Takes a few minutes on a CPU.
@@ -30,7 +34,7 @@ import subprocess
 import sys
 import tempfile
 
-PROGRAMS = ("sim-rainbow", "sim-flat-static", "decode-step")
+PROGRAMS = ("sim-rainbow", "sim-flat-static", "decode-step", "decode-step-kernel")
 
 
 def strip_metadata(text: str) -> str:
@@ -57,6 +61,27 @@ def strip_metadata(text: str) -> str:
         elif not skip:
             lines.append(line)
     return "\n".join(lines)
+
+
+def kernel_bodies_without_locations(text: str) -> str:
+    """`text` with each Mosaic kernel's base64 MLIR bytecode ("body") replaced
+    by the SHA-256 of the module printed without debug locations: a kernel
+    whose source lines moved is the same kernel."""
+    import base64
+    import hashlib
+
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    def digest(m):
+        with ir.Context() as ctx, ir.Location.unknown():
+            tpu.register_dialect(ctx)
+            ctx.allow_unregistered_dialects = True
+            module = ir.Module.parse(base64.b64decode(m.group(1)))
+            asm = module.operation.get_asm(enable_debug_info=False)
+        return '"body":"sha256:' + hashlib.sha256(asm.encode()).hexdigest() + '"'
+
+    return re.sub(r'"body":"([A-Za-z0-9+/=]+)"', digest, text)
 
 
 def renumbered(text: str) -> str:
@@ -90,7 +115,8 @@ def dump(tree: pathlib.Path, out: pathlib.Path) -> None:
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev), tree_)
 
     def record(name, lowered):
-        (out / f"{name}.hlo").write_text(strip_metadata(lowered.compile().as_text()))
+        text = strip_metadata(lowered.compile().as_text())
+        (out / f"{name}.hlo").write_text(kernel_bodies_without_locations(text))
 
     bench = harness.Bench(tree)
     cfg = bench.config("gups")
@@ -120,6 +146,11 @@ def dump(tree: pathlib.Path, out: pathlib.Path) -> None:
     tok = jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=dev)
     step = jax.jit(lambda p, t, k: rainbow_decode_step(mcfg, pcfg, p, t, k))
     record("decode-step", step.lower(sds(params), tok, sds(kv)))
+    from repro.kernels.rainbow_attention import ops as ra_ops
+
+    ra_ops.backend = lambda *a, **k: "pallas"  # what it picks on a TPU
+    step = jax.jit(lambda p, t, k: rainbow_decode_step(mcfg, pcfg, p, t, k))
+    record("decode-step-kernel", step.lower(sds(params), tok, sds(kv)))
 
 
 def main(argv: list[str]) -> int:

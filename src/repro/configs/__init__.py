@@ -16,6 +16,7 @@ ARCH_IDS = [
     "hymba-1.5b",
     "internvl2-2b",
     "mamba2-1.3b",
+    "moonlight-16b-a3b",
 ]
 
 

@@ -195,14 +195,14 @@ class DramState:
 
 
 def dram_init(num_slots: int) -> DramState:
-    z = jnp.zeros((num_slots,), jnp.int32)
+    # every field its own buffer, so a jitted step may donate the state
     return DramState(
-        slot_state=z,
+        slot_state=jnp.zeros((num_slots,), jnp.int32),
         slot_sp=jnp.full((num_slots,), -1, jnp.int32),
         slot_page=jnp.full((num_slots,), -1, jnp.int32),
         slot_reads=jnp.zeros((num_slots,), jnp.float32),
         slot_writes=jnp.zeros((num_slots,), jnp.float32),
-        last_touch=z,
+        last_touch=jnp.zeros((num_slots,), jnp.int32),
     )
 
 

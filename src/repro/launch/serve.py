@@ -86,15 +86,28 @@ class Generation:
     """One batched greedy decode."""
 
     tokens: jax.Array  # int32[B, new]: the greedy continuation
-    logits: jax.Array  # f32[B, new, V]: the logits each new token came from
+    logits: jax.Array | None  # f32[B, new, V]: the logits each new token came from
     promoted: int | None  # hot KV blocks promoted (paged cache only)
     seconds: float  # wall time, compilation included
+    # routed slots (token, expert) that landed on the experts held here,
+    # summed over the call's steps and MoE layers (paged MoE models only)
+    local_expert_slots: int | None = None
+
+
+# The paged decode step, jitted once for every call: a later call with the
+# same model, cache config and shapes runs the compiled program it already
+# has, and the cache is donated, so each step updates the pools in place
+# instead of copying them whole.
+_paged_step = jax.jit(rainbow_decode_step, static_argnums=(0, 1),
+                      static_argnames=("collect_slots",), donate_argnums=(4,))
 
 
 def generate(cfg, params, prompt: jax.Array, new_tokens: int,
-             pcfg: PagedConfig | None = None) -> Generation:
+             pcfg: PagedConfig | None = None, keep_logits: bool = True) -> Generation:
     """Greedy decode after `prompt` over the flat KV cache, or over the
-    Rainbow-paged cache when `pcfg` is given.
+    Rainbow-paged cache when `pcfg` is given. With keep_logits=False only
+    the tokens are kept (`logits` is None): the stacked float32 logits of
+    a large batch and vocabulary would not fit the device.
 
     The host work sits in profiler spans on the device trace's clock:
     "serve.init" (cache and step set-up), one "serve.step" step annotation
@@ -103,13 +116,20 @@ def generate(cfg, params, prompt: jax.Array, new_tokens: int,
     enable_compile_cache()
     b, plen = prompt.shape
     t0 = time.perf_counter()
+    # a paged MoE step also returns its routed slots on the held experts
+    counted = pcfg is not None and cfg.family == "moe"
+    slots = []
     with jax.profiler.TraceAnnotation("serve.init"):
         if pcfg is None:
             cache = M.init_cache(cfg, b, plen + new_tokens, tp=1)
             step = jax.jit(lambda p, t, c: M.decode_step(cfg, p, t, c))
         else:
             cache = paged_init(cfg, pcfg, b, 1, cfg.num_layers)
-            step = jax.jit(lambda p, t, k: rainbow_decode_step(cfg, pcfg, p, t, k))
+
+            def step(p, t, k):
+                out = _paged_step(cfg, pcfg, p, t, k, collect_slots=counted)
+                slots.extend(out[2:])
+                return out[:2]
     if pcfg is None:
         with jax.profiler.StepTraceAnnotation("serve.step", step_num=0):
             logits, cache = M.prefill(cfg, params, {"tokens": prompt}, cache, tp=1)
@@ -126,13 +146,18 @@ def generate(cfg, params, prompt: jax.Array, new_tokens: int,
         with jax.profiler.StepTraceAnnotation("serve.step", step_num=first + i):
             if i:
                 logits, cache = step(params, tokens[-1], cache)
-            seen.append(logits[:, -1])
+            if keep_logits:
+                seen.append(logits[:, -1])
             tokens.append(greedy_sample(logits, cfg.vocab_size))
     with jax.profiler.TraceAnnotation("serve.readback"):
-        out = jax.block_until_ready(
-            (jnp.concatenate(tokens, axis=1), jnp.stack(seen, axis=1)))
+        if keep_logits:
+            out = jax.block_until_ready(
+                (jnp.concatenate(tokens, axis=1), jnp.stack(seen, axis=1)))
+        else:
+            out = (jax.block_until_ready(jnp.concatenate(tokens, axis=1)), None)
         promoted = None if pcfg is None else int((cache.remap.remap >= 0).sum())
-    return Generation(*out, promoted, time.perf_counter() - t0)
+        local = int(jnp.stack(slots).sum()) if counted else None
+    return Generation(*out, promoted, time.perf_counter() - t0, local)
 
 
 def main() -> None:
@@ -172,11 +197,14 @@ def main() -> None:
     cfg = get_reduced_config(args.arch) if args.reduced else get_config(args.arch)
     if args.prompt_len < 1 or args.tokens < 1:
         ap.error("--prompt-len and --tokens must be >= 1")
-    if args.kv == "paged" and cfg.family not in ("dense", "vlm"):
+    if args.kv == "paged" and cfg.family not in ("dense", "vlm") and not cfg.mla:
         ap.error(
-            f"--kv paged targets dense-family archs; --arch {args.arch} is "
-            f"family {cfg.family!r} (use --kv flat)"
+            f"--kv paged targets dense-family and latent-attention archs; --arch "
+            f"{args.arch} is family {cfg.family!r} (use --kv flat)"
         )
+    if args.kv == "flat" and cfg.mla:
+        ap.error(f"--arch {args.arch} uses latent attention, which decodes over "
+                 "the paged cache only; use --kv paged")
     if args.kv == "flat":
         ignored = [
             flag for flag, v in [

@@ -143,6 +143,10 @@ class RainbowKV:
 
     cap_k/cap_v: [L, B*blocks_per_seq, block, KVS, hd]  capacity pool
     hot_k/hot_v: [L, hot_slots, block, KVS, hd]         hot pool
+                 For multi-head latent attention (cfg.mla) there is one
+                 latent pool per tier: cap_k/hot_k [L, n, block, 1,
+                 cfg.latent_width] hold each token's row [c ++ k_rope ++
+                 zeros], and cap_v/hot_v are None.
     remap:       RemapState over (superblock=seq, page=block) — shared by layers
                  (hotness is measured summed over layers; per-layer remap is a
                  config away but multiplies table traffic for little gain)
@@ -166,17 +170,21 @@ class RainbowKV:
 
 
 def paged_init(cfg, pcfg: PagedConfig, batch: int, tp: int, layers: int) -> RainbowKV:
-    kvs = cfg.kv_store(tp)
-    hd = cfg.head_dim
+    if cfg.mla:
+        if pcfg.quantize:
+            raise NotImplementedError("the int8 pools hold per-head K/V, not latent rows")
+        kvs, hd = 1, cfg.latent_width
+    else:
+        kvs, hd = cfg.kv_store(tp), cfg.head_dim
     dt = jnp.int8 if pcfg.quantize else jnp.dtype(cfg.dtype)
     nb = batch * pcfg.blocks_per_seq
     shape_cap = (layers, nb, pcfg.block_size, kvs, hd)
     shape_hot = (layers, pcfg.hot_slots, pcfg.block_size, kvs, hd)
     kv = RainbowKV(
         cap_k=jnp.zeros(shape_cap, dt),
-        cap_v=jnp.zeros(shape_cap, dt),
+        cap_v=None if cfg.mla else jnp.zeros(shape_cap, dt),
         hot_k=jnp.zeros(shape_hot, dt),
-        hot_v=jnp.zeros(shape_hot, dt),
+        hot_v=None if cfg.mla else jnp.zeros(shape_hot, dt),
         remap=remap_init(batch, pcfg.blocks_per_seq),
         s1=counting.stage1_init(batch),
         s2=counting.stage2_init(pcfg.top_n, pcfg.blocks_per_seq),
@@ -247,7 +255,8 @@ def append_token(
 
     k_new/v_new: [L, B, KVS, hd]. New tokens go to their home capacity block —
     DRAM-preferred placement happens via promotion (fresh blocks are usually
-    hot and get promoted at the next interval).
+    hot and get promoted at the next interval). With latent pools, k_new is
+    the latent row and v_new is None.
     """
     lyr, b, kvs, hd = k_new.shape
     pos = kv.length
@@ -255,6 +264,8 @@ def append_token(
     off = pos % pcfg.block_size
     seq_ids = jnp.arange(b)
     flat_block = seq_ids * pcfg.blocks_per_seq + blk  # [B]
+    if v_new is None:
+        return _append_latent(kv, seq_ids, blk, flat_block, off, k_new)
     cap_k = kv.cap_k.at[:, flat_block, off].set(k_new.astype(kv.cap_k.dtype))
     cap_v = kv.cap_v.at[:, flat_block, off].set(v_new.astype(kv.cap_v.dtype))
     # Paper §III-E cases 1/2: writes to a migrated page must land on the fast
@@ -270,6 +281,24 @@ def append_token(
         v_new.astype(kv.hot_v.dtype), mode="drop"
     )
     return _replace(kv, cap_k=cap_k, cap_v=cap_v, hot_k=hot_k, hot_v=hot_v)
+
+
+def _append_latent(kv: RainbowKV, seq_ids, blk, flat_block, off, row) -> RainbowKV:
+    """append_token for latent pools: each sequence's current block is read,
+    the row at `off` replaced, and the whole block written back. A latent
+    block's (token, lane) plane is the tiled one, so a one-row scatter would
+    make XLA lay the whole pool out again (twice per step); whole blocks
+    keep its layout. Resident blocks get the row in their hot slot too."""
+
+    def write(pool, idx):
+        cur = pool.at[:, idx].get(mode="clip")  # [L, B, block, 1, W]
+        here = (jnp.arange(pool.shape[2]) == off)[None, None, :, None, None]
+        new = jnp.where(here, row[:, :, None].astype(pool.dtype), cur)
+        return pool.at[:, idx].set(new, mode="drop")
+
+    resident, slot = translate(kv.remap, seq_ids, jnp.full(seq_ids.shape, blk))
+    slot_safe = jnp.where(resident, slot, kv.hot_k.shape[1])  # OOB -> dropped
+    return _replace(kv, cap_k=write(kv.cap_k, flat_block), hot_k=write(kv.hot_k, slot_safe))
 
 
 def append_token_q8(
@@ -390,9 +419,9 @@ def end_interval_promote(
     # invalid lanes scatter out of bounds and are dropped (no slot-0 races)
     dst = jnp.where(plan.migrate, plan.dst_slot, pcfg.hot_slots).astype(jnp.int32)
     gathered_k = kv.cap_k[:, src]  # [L, K, block, KVS, hd]
-    gathered_v = kv.cap_v[:, src]
+    gathered_v = None if kv.cap_v is None else kv.cap_v[:, src]  # None: latent pools
     hot_k = kv.hot_k.at[:, dst].set(gathered_k, mode="drop")
-    hot_v = kv.hot_v.at[:, dst].set(gathered_v, mode="drop")
+    hot_v = None if gathered_v is None else kv.hot_v.at[:, dst].set(gathered_v, mode="drop")
 
     s1, new_psn, dram = control.rotate_monitors(ctrl, kv.s1, out.dram)
     new = _replace(

@@ -31,6 +31,23 @@ class ModelConfig:
     moe_d_ff: int = 0  # per-expert ffn width (fine-grained MoE)
     moe_first_dense: int = 0  # leading dense-FFN layers (deepseek layer 0)
     moe_capacity_factor: float = 1.25
+    # routing: "softmax" over the experts, or "sigmoid" scores whose top-k is
+    # chosen with a learned correction bias added (DeepSeek-V3 noaux_tc); the
+    # top-k weights are renormalized or not, then scaled
+    moe_scoring: Literal["softmax", "sigmoid"] = "softmax"
+    moe_norm_topk: bool = True
+    moe_routed_scale: float = 1.0
+    # the experts held here, under expert parallelism: ids
+    # moe_expert_offset .. + moe_experts_held - 1 (0 = all of them); the
+    # router keeps all moe_num_experts outputs
+    moe_experts_held: int = 0
+    moe_expert_offset: int = 0
+
+    # --- multi-head latent attention (DeepSeek-V2/V3); 0 = off ---
+    mla_kv_rank: int = 0  # latent (compressed KV) width
+    mla_nope_dim: int = 0  # per-head query/key width without rotary embedding
+    mla_rope_dim: int = 0  # rotary width: per query head, and one key shared by all
+    mla_v_dim: int = 0  # per-head value width
 
     # --- SSM (mamba2 / hymba) ---
     ssm_state: int = 0
@@ -42,6 +59,7 @@ class ModelConfig:
     # --- attention flavor ---
     qk_norm: bool = False
     rope_theta: float = 1e6
+    rope_interleave: bool = False  # rotary pairs are adjacent lanes (DeepSeek-V3)
     sliding_window: int = 0  # 0 = full attention
     global_attn_every: int = 0  # hybrid: every k-th layer uses full attention
 
@@ -55,6 +73,7 @@ class ModelConfig:
 
     # --- norms/activations ---
     norm: Literal["rmsnorm", "layernorm"] = "rmsnorm"
+    norm_eps: float = 1e-6
     act: Literal["silu", "gelu"] = "silu"
     gated_mlp: bool = True
     tie_embeddings: bool = False
@@ -73,6 +92,20 @@ class ModelConfig:
     @property
     def padded_vocab(self) -> int:
         return _ceil_to(self.vocab_size, self.vocab_pad_multiple)
+
+    @property
+    def mla(self) -> bool:
+        return self.mla_kv_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Lanes of one cached MLA row: the latent and the shared rotary key,
+        padded to the 128-lane tile."""
+        return _ceil_to(self.mla_kv_rank + self.mla_rope_dim, 128)
+
+    @property
+    def moe_held(self) -> int:
+        return self.moe_experts_held or self.moe_num_experts
 
     def padded_heads(self, tp: int) -> int:
         if self.attn_free:
@@ -106,7 +139,9 @@ class ModelConfig:
         n = v * d * (1 if self.tie_embeddings else 2)
         hd = self.head_dim
         per_layer = 0
-        if not self.attn_free:
+        if self.mla:
+            per_layer += self._mla_params()
+        elif not self.attn_free:
             h, kv = self.num_heads, self.num_kv_heads
             per_layer += d * h * hd + 2 * d * kv * hd + h * hd * d
         if self.family == "moe":
@@ -134,6 +169,12 @@ class ModelConfig:
             n += l * (d * h * hd + 2 * d * kv * hd + h * hd * d)
         return n
 
+    def _mla_params(self) -> int:
+        d, h, r = self.d_model, self.num_heads, self.mla_kv_rank
+        qk = self.mla_nope_dim + self.mla_rope_dim
+        return (d * h * qk + d * (r + self.mla_rope_dim) + r
+                + r * h * (self.mla_nope_dim + self.mla_v_dim) + h * self.mla_v_dim * d)
+
     def active_param_count(self) -> int:
         """Active params per token (MoE counts only routed top-k + shared)."""
         if self.family != "moe":
@@ -143,6 +184,8 @@ class ModelConfig:
         hd = self.head_dim
         h, kv = self.num_heads, self.num_kv_heads
         per_layer = d * h * hd + 2 * d * kv * hd + h * hd * d + d * self.moe_num_experts
+        if self.mla:
+            per_layer += self._mla_params() - (d * h * hd + 2 * d * kv * hd + h * hd * d)
         factor = 3 if self.gated_mlp else 2
         per_layer += (self.moe_top_k + self.moe_num_shared) * factor * d * self.moe_d_ff
         return n + l * per_layer

@@ -48,7 +48,8 @@ def norm_specs(cfg, stacked: bool = False) -> Params:
     return p
 
 
-def apply_norm(cfg, p: Params, x: jax.Array, eps: float = 1e-6) -> jax.Array:
+def apply_norm(cfg, p: Params, x: jax.Array, eps: float | None = None) -> jax.Array:
+    eps = cfg.norm_eps if eps is None else eps
     xf = x.astype(jnp.float32)
     if cfg.norm == "layernorm":
         mu = xf.mean(-1, keepdims=True)

@@ -31,6 +31,7 @@ from jax.sharding import PartitionSpec as P
 from repro.launch.axes import BATCH_AXES, MODEL_AXIS
 from repro.models import attention as attn
 from repro.models import layers as L
+from repro.models import mla
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.config import ModelConfig
@@ -109,7 +110,9 @@ def _seg_init(cfg, key, tp, seg: SegSpec) -> Params:
     n = seg.length
     ks = jax.random.split(key, 8)
     p: Params = {"ln1": L.norm_init(cfg, cfg.d_model, n)}
-    if seg.kind in ("dense", "moe", "hybrid", "encdec"):
+    if cfg.mla:
+        p["attn"] = mla.attn_init(cfg, ks[0], stacked=n)
+    elif seg.kind in ("dense", "moe", "hybrid", "encdec"):
         p["attn"] = attn.attn_init(cfg, ks[0], tp, stacked=n)
     if seg.kind == "ssm" or seg.kind == "hybrid":
         p["ssm"] = ssm_mod.ssm_init(cfg, ks[1], tp, stacked=n)
@@ -127,7 +130,9 @@ def _seg_init(cfg, key, tp, seg: SegSpec) -> Params:
 
 def _seg_specs(cfg, seg: SegSpec) -> Params:
     p: Params = {"ln1": L.norm_specs(cfg, stacked=True)}
-    if seg.kind in ("dense", "moe", "hybrid", "encdec"):
+    if cfg.mla:
+        p["attn"] = mla.attn_specs(cfg, stacked=True)
+    elif seg.kind in ("dense", "moe", "hybrid", "encdec"):
         p["attn"] = attn.attn_specs(cfg, stacked=True)
     if seg.kind in ("ssm", "hybrid"):
         p["ssm"] = ssm_mod.ssm_specs(cfg, stacked=True)
@@ -224,6 +229,15 @@ def _block_full_seq(cfg, kind, pl, x, positions, window, tp, sc, impl, enc_out=N
         ssm_states = (conv_st, ssm_st)
         h2 = L.apply_norm(cfg, pl["ln2"], x)
         x = x + L.apply_mlp(cfg, pl["mlp"], h2, sc=sc)
+    elif cfg.mla:
+        qp = positions if positions.ndim == 2 else positions[None]
+        mask = attn._causal_window_mask(qp, qp, window, True)[:, None]
+        x = x + mla.attend_full_seq(cfg, pl["attn"], h, positions, mask)
+        h2 = L.apply_norm(cfg, pl["ln2"], x)
+        if kind == "moe":
+            x = x + moe_mod.apply_moe(cfg, pl["moe"], h2, tp, sc=sc)
+        else:
+            x = x + L.apply_mlp(cfg, pl["mlp"], h2, sc=sc)
     else:
         causal = kind != "encoder"
         ao, k, v = _attn_full_seq(
@@ -383,6 +397,10 @@ def loss_fn(cfg, params, batch, tp=1, sc=None, attn_impl="dense", remat="none"):
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, tp: int = 1) -> Params:
+    if cfg.mla:
+        raise NotImplementedError(
+            f"{cfg.name}: latent attention decodes over the Rainbow-paged cache "
+            "(repro.serving.rainbow_decode); the flat cache holds per-head K/V")
     cache: Params = {"len": jnp.zeros((), jnp.int32)}
     for seg in segments(cfg):
         c: Params = {}

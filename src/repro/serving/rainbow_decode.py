@@ -14,6 +14,9 @@ Read modes:
              approximation (H2O/Quest-style) is opt-in; any block whose mass
              grows gets promoted and rejoins the read set.
 
+Latent-attention (MLA) models read one latent row per token from a single
+pool per tier, in full mode only (`_mla_layers`; docs/paged_decode.md).
+
 Each decode step records per-block attention mass (the access stream of the
 paper's memory controller); every `interval_steps`, end_interval_promote() runs
 two-stage classification + utility admission and copies hot blocks.
@@ -47,7 +50,9 @@ from repro.memory.kvcache import (
 )
 from repro.models import attention as attn
 from repro.models import layers as L
+from repro.models import mla
 from repro.models import model as M
+from repro.models import moe as moe_mod
 
 
 def _attend_with_mass(q, k, v, valid, block_size, nblk):
@@ -147,8 +152,10 @@ def rainbow_decode_step(
     mode: str = "full",
     scales: dict | None = None,  # int8 mode (pcfg.quantize): scale side pytree
     collect_mass: bool = False,  # also return this step's [B, nblk] block mass
+    collect_slots: bool = False,  # also return the step's routed slots on held experts
 ):
-    """One decode step for a dense-family LM over the Rainbow paged cache.
+    """One decode step for a dense-family LM, or a latent-attention MoE LM
+    (DeepSeek-V3 block: `_mla_layers`), over the Rainbow paged cache.
 
     Its phases run under named scopes, so each op of the step names its
     phase in its op_name metadata: "translate" (pool indices and the sparse
@@ -160,8 +167,18 @@ def rainbow_decode_step(
     no "read"; the layer scan then carries only the parameters and the
     layer index, and the kernel DMAs each live block of that layer from the
     stacked pools.
+
+    Returns (logits, kv), then the int8 scales, the block mass and the
+    routed slots on held experts (an int32 scalar, 0 without experts) where
+    asked for.
     """
-    assert cfg.family in ("dense", "vlm"), "rainbow decode targets dense-family archs"
+    if cfg.mla:
+        if mode != "full" or pcfg.quantize:
+            raise NotImplementedError(
+                f"{cfg.name}: latent attention reads the paged cache in full mode "
+                "over bfloat16 pools only (no sparse or int8 read of latent rows)")
+    else:
+        assert cfg.family in ("dense", "vlm"), "rainbow decode targets dense-family archs"
     b = tokens.shape[0]
     cur = kv.length
     x = L.embed_lookup(cfg, params["embed"], tokens)
@@ -170,7 +187,7 @@ def rainbow_decode_step(
 
     seg = M.segments(cfg)[0]
     seg_params = params["segments"][seg.name]
-    kernel = (mode == "full" and not pcfg.quantize
+    kernel = (mode == "full" and not pcfg.quantize and not cfg.mla
               and ra_ops.backend(pcfg.block_size, cfg.head_dim) != "ref")
 
     # Translation is layer-invariant: compute the virtual pool indices once.
@@ -255,18 +272,22 @@ def rainbow_decode_step(
             h = h + L.apply_mlp(cfg, pl["mlp"], h2, sc=sc)
         return h, (k_new[:, 0], v_new[:, 0], blk_mass)
 
-    if kernel:
-        xs = (seg_params, jnp.arange(kv.cap_k.shape[0], dtype=jnp.int32))
-    elif pcfg.quantize:
-        xs = (seg_params, kv.cap_k, kv.cap_v, kv.hot_k, kv.hot_v,
-              scales["cap_k"], scales["cap_v"], scales["hot_k"], scales["hot_v"])
+    if cfg.mla:
+        h, k_all, mass_all, slots = _mla_layers(cfg, params, x, pos, kv, read_idx, cur)
+        v_all = None  # one latent pool
     else:
-        xs = (seg_params, kv.cap_k, kv.cap_v, kv.hot_k, kv.hot_v)
-    # "layers" names what the scan itself adds around the body's scopes: the
-    # per-layer slices of the stacked pools (none on the kernel path) and
-    # what XLA fuses into them
-    with jax.named_scope("layers"):
-        h, (k_all, v_all, mass_all) = jax.lax.scan(body, x, xs)
+        if kernel:
+            xs = (seg_params, jnp.arange(kv.cap_k.shape[0], dtype=jnp.int32))
+        elif pcfg.quantize:
+            xs = (seg_params, kv.cap_k, kv.cap_v, kv.hot_k, kv.hot_v,
+                  scales["cap_k"], scales["cap_v"], scales["hot_k"], scales["hot_v"])
+        else:
+            xs = (seg_params, kv.cap_k, kv.cap_v, kv.hot_k, kv.hot_v)
+        # "layers" names what the scan itself adds around the body's scopes:
+        # the per-layer slices of the stacked pools (none on the kernel path)
+        # and what XLA fuses into them
+        with jax.named_scope("layers"):
+            h, (k_all, v_all, mass_all) = jax.lax.scan(body, x, xs)
 
     with jax.named_scope("append"):
         if pcfg.quantize:
@@ -305,7 +326,66 @@ def rainbow_decode_step(
     out = (logits, kv) + ((scales,) if pcfg.quantize else ())
     if collect_mass:
         out = out + (step_mass,)
+    if collect_slots:
+        out = out + (slots if cfg.mla else jnp.zeros((), jnp.int32),)
     return out
+
+
+def _mla_layers(cfg, params, x, pos, kv: RainbowKV, read_idx, cur):
+    """The layers of a latent-attention (MLA) model, one scan per segment:
+    the leading dense-MLP layers, then the MoE layers.
+
+    Per layer: "qkv" (norm, projections, the latent row), "absorb" (W_UK
+    into the query; W_UV and the output projection on the way out),
+    "attend" (ops.paged_decode_attention in its latent mode: on a TPU the
+    rainbow_attention kernel as "attend/latent_attention", else the jnp
+    read), then "mlp" on a dense layer, or "route", "experts" and "shared"
+    (models.moe.apply_moe_held) on a MoE layer. The scan carries the
+    parameters and the layer index; the attention reads the stacked pools.
+
+    Returns (h, latent rows [L, B, 1, W], block mass [L, B, nblk], routed
+    slots on the held experts)."""
+
+    def body(kind):
+        def step(h, xs):
+            pl, layer = xs
+            with jax.named_scope("qkv"):
+                hn = L.apply_norm(cfg, pl["ln1"], h)
+                q_nope, q_rope, c, k_rope = mla.project(cfg, pl["attn"], hn, pos)
+                row = mla.latent_row(cfg, c, k_rope)[:, 0, None]  # [B, 1, W]
+            with jax.named_scope("absorb"):
+                q = mla.absorb_query(cfg, pl["attn"], q_nope, q_rope)[:, 0]
+            with jax.named_scope("attend"):
+                o, blk_mass = ra_ops.paged_decode_attention(
+                    q, row, None, kv.cap_k, None, kv.hot_k, None, read_idx, layer, cur,
+                    scale=mla.softmax_scale(cfg), v_dim=cfg.mla_kv_rank)
+            with jax.named_scope("absorb"):
+                h = h + mla.absorb_output(cfg, pl["attn"], o[:, None])
+            if kind == "moe":
+                with jax.named_scope("route"):
+                    h2 = L.apply_norm(cfg, pl["ln2"], h)
+                routed, shared, slots = moe_mod.apply_moe_held(cfg, pl["moe"], h2)
+                h = h + (routed + shared)
+            else:
+                with jax.named_scope("mlp"):
+                    h2 = L.apply_norm(cfg, pl["ln2"], h)
+                    h = h + L.apply_mlp(cfg, pl["mlp"], h2)
+                slots = jnp.zeros((), jnp.int32)
+            return h, (row, blk_mass, slots)
+
+        return step
+
+    rows, masses, slots = [], [], jnp.zeros((), jnp.int32)
+    h = x
+    for seg in M.segments(cfg):
+        layers = jnp.arange(seg.start, seg.start + seg.length, dtype=jnp.int32)
+        with jax.named_scope("layers"):
+            h, (r, m, sl) = jax.lax.scan(
+                body(seg.kind), h, (params["segments"][seg.name], layers))
+        rows.append(r)
+        masses.append(m)
+        slots = slots + sl.sum()
+    return h, jnp.concatenate(rows), jnp.concatenate(masses), slots
 
 
 def record_mass_trace(

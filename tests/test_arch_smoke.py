@@ -73,6 +73,7 @@ def test_full_config_construction(arch):
         "hymba-1.5b": (1.2e9, 2.2e9),
         "internvl2-2b": (1.7e9, 2.6e9),
         "mamba2-1.3b": (1.0e9, 1.8e9),
+        "moonlight-16b-a3b": (15e9, 17e9),
     }[arch]
     assert expected[0] <= n <= expected[1], f"{arch}: {n:.3e}"
     # padded heads divide cleanly under tp=16 (the production mesh)

@@ -25,6 +25,7 @@ WALK = {"tlb", "tlb4k", "tlb2m", "bmc"}
 CONTROL = {"observe", "plan", "apply"}
 DECODE = {"translate", "layers", "qkv", "read", "attend", "mlp", "append", "observe",
           "promote", "logits"}
+MLA = DECODE - {"read"} | {"absorb", "route", "experts", "shared"}
 SCENARIO, ACCESSES = "syn/GUPS", 256
 
 # (case, EngineSpec keywords, the scopes its fused program must carry)
@@ -44,7 +45,13 @@ DECODE_CASES = [("decode-full", {}, DECODE), ("decode-sparse", {"mode": "sparse"
                 # where the rainbow_attention kernel reads the pools (a TPU picks
                 # it; forced here), it runs under "attend/paged_attention"
                 ("decode-full-kernel", {"kernel": True},
-                 DECODE - {"read"} | {"paged_attention"})]
+                 DECODE - {"read"} | {"paged_attention"}),
+                # a latent-attention MoE model: absorbed projections, the
+                # latent read inside "attend", the MoE phases; its layer 0 is
+                # dense ("mlp")
+                ("decode-mla", {"arch": "moonlight-16b-a3b"}, MLA),
+                ("decode-mla-kernel", {"arch": "moonlight-16b-a3b", "kernel": True},
+                 MLA | {"latent_attention"})]
 
 
 def scope_segments(lowered) -> set[str]:
@@ -64,7 +71,7 @@ def _engine(kw):
 
 
 def _decode(kw):
-    cfg = get_reduced_config("qwen3-4b")
+    cfg = get_reduced_config(kw.get("arch", "qwen3-4b"))
     b, s = 2, 16
     pcfg = PagedConfig(block_size=4, blocks_per_seq=s // 4, hot_slots=2, top_n=2,
                        max_promotions=2, interval_steps=2,

@@ -141,3 +141,57 @@ def test_paged_decode_step_compiles_for_v5e(one_chip, monkeypatch):
                   "[{},{},{},{}]".format(per_layer_pool[0] + DECODE_HOT,
                                          *per_layer_pool[1:])):
         assert not re.search(r"\w+" + re.escape(shape), text), shape
+
+
+# the latent-attention MoE decode cell: batch 128, 128 blocks of 16 tokens
+# per sequence, a hot pool of 64 blocks (build_paged_config(128, 16)); layer 0
+# dense and 8 MoE layers holding 16 of 64 experts
+MLA_BATCH, MLA_NBLK, MLA_LAYERS, MLA_HELD = 128, 128, 9, 16
+
+
+def _computations(text):
+    """{computation name: its HLO text} of a module's text."""
+    out, name = {}, None
+    for line in text.split("\n"):
+        m = re.match(r"(?:ENTRY )?%(\S+) ", line)
+        if m and line.rstrip().endswith("{"):
+            name = m.group(1)
+            out[name] = []
+        elif name is not None:
+            out[name].append(line)
+    return {k: "\n".join(v) for k, v in out.items()}
+
+
+def test_mla_moe_decode_step_compiles_for_v5e(one_chip, monkeypatch):
+    """The latent-attention MoE decode step at the cell's widths: the MoE
+    layer loop's body calls the kernel's latent mode once, the dense layer
+    once more, and nothing gathers, slices or concatenates latent rows at
+    the provisioned capacity (2,048 positions per sequence)."""
+    from repro.launch.serve import build_paged_config
+    from repro.memory.kvcache import paged_init
+    from repro.models import model as M
+    from repro.serving.rainbow_decode import rainbow_decode_step
+
+    monkeypatch.setattr(ra_ops, "backend", lambda *a, **k: "pallas")
+    cfg = dataclasses.replace(get_config("moonlight-16b-a3b"), num_layers=MLA_LAYERS,
+                              moe_experts_held=MLA_HELD)
+    pcfg = build_paged_config(MLA_NBLK, DECODE_BLOCK)
+    params = jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0), tp=1))
+    kv = jax.eval_shape(lambda: paged_init(cfg, pcfg, MLA_BATCH, 1, cfg.num_layers))
+    on_chip = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)
+    tokens = jax.ShapeDtypeStruct((MLA_BATCH, 1), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, t, k: rainbow_decode_step(cfg, pcfg, p, t, k,
+                                                       collect_slots=True))
+    text = step.lower(on_chip(params), tokens, on_chip(kv)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    comps = _computations(text)
+    bodies = [comps[b] for b in re.findall(r"body=%([\w.\-]+)", text)]
+    assert sum('custom_call_target="tpu_custom_call"' in b for b in bodies) == 1
+    # (the hidden state is [128, 2048] too: a latent read over the capacity
+    # would be [128, 2048, (1,) 640] rows or [128, 16, 2048] scores)
+    b, n, w, h = MLA_BATCH, MLA_NBLK * DECODE_BLOCK, cfg.latent_width, cfg.num_heads
+    for shape in (f"[{b},{n},1,{w}]", f"[{b},{n},{w}]", f"[{b},{h},{n}]", f"[{b},{n + 1}",
+                  f"[{b},{h},{n + 1}]", f"[{b * MLA_NBLK},{DECODE_BLOCK},",
+                  f"[{b * MLA_NBLK + pcfg.hot_slots},{DECODE_BLOCK},"):
+        assert not re.search(r"\w+" + re.escape(shape), text), shape
